@@ -112,4 +112,7 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -90,4 +90,7 @@ def run(out: str = "BENCH_serving.json", *, requests: int = 4096,
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

@@ -147,6 +147,32 @@ BUILDERS = {
 }
 
 
+def make_workflow(arch: str, target: str, *, verify: bool):
+    """The demo's Workflow for ``arch`` on ``target``, and the builder of
+    the stepper it lowers (the RTL target needs the model graph)."""
+    from repro.core.types import shape_table_for, shapes_for
+    from repro.energy.hw import XC7S15
+
+    cfg = get_config(arch)
+    infer_shape = shapes_for(cfg)[0]             # "infer_1" for both archs
+    creator = Creator(hw=XC7S15) if target == "rtl" else Creator()
+    train_fn, step_builder = BUILDERS[arch]
+
+    def stepper_builder(knobs):
+        return creator.build(cfg, shape_table_for(cfg)[infer_shape])
+
+    wf = Workflow(creator=creator, train_fn=train_fn,
+                  step_builder=step_builder, target=target,
+                  stepper_builder=stepper_builder if target == "rtl"
+                  else None, verify=verify,
+                  analyze="error" if target == "rtl" else None)
+    return wf, stepper_builder
+
+
+#: the knobs the feedback loop starts from
+INITIAL_KNOBS = {"bits": 4, "frac": 2}
+
+
 def optimizer(history):
     """The feedback rule a developer would apply after reading the reports:
     eval loss too high -> widen the fixed-point format."""
@@ -210,7 +236,6 @@ def main():
     target = args.target
     arch = ARCH_ALIASES.get(args.arch, args.arch)
     TRAIN_STEPS = args.train_steps
-    from repro.core.types import shapes_for
     from repro.energy.hw import XC7S15
 
     cap = None
@@ -221,22 +246,10 @@ def main():
         cap.__enter__()                  # closed (and written) at the end
 
     cfg = get_config(arch)
-    infer_shape = shapes_for(cfg)[0]             # "infer_1" for both archs
-    creator = Creator(hw=XC7S15) if target == "rtl" else Creator()
-    train_fn, step_builder = BUILDERS[arch]
-
-    def stepper_builder(knobs):
-        from repro.core.types import shape_table_for
-
-        return creator.build(cfg, shape_table_for(cfg)[infer_shape])
-
-    wf = Workflow(creator=creator, train_fn=train_fn,
-                  step_builder=step_builder, target=target,
-                  stepper_builder=stepper_builder if target == "rtl"
-                  else None, verify=args.verify,
-                  analyze="error" if target == "rtl" else None)
+    train_fn, _ = BUILDERS[arch]
+    wf, stepper_builder = make_workflow(arch, target, verify=args.verify)
     req = Requirement(max_eval_loss=0.01, max_latency_s=1.0)
-    hist = wf.run(req, optimizer, {"bits": 4, "frac": 2},
+    hist = wf.run(req, optimizer, INITIAL_KNOBS,
                   max_iters=args.max_iters)
     print(f"\n{'it':>3} {'fmt':>7} {'eval':>8} {'est_ms':>8} {'meas_ms':>8} "
           f"{'est_uJ':>8} {'GOP/J':>7} {'vrfy':>4} {'ok':>3}")
@@ -356,4 +369,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -197,8 +197,8 @@ class Deployment:
 
 @dataclass
 class XLADeployment(Deployment):
-    """The jitted-executable deployment: wall-clock timing on the container
-    (our Elastic-Node proxy) with duty-1 power from the HWSpec."""
+    """The jitted-executable deployment: wall-clock timing on the device
+    JAX runs it on, with duty-1 power from the HWSpec."""
 
     fn: Any                                     # compiled/jitted callable
     hw: HWSpec = TPU_V5E
@@ -240,8 +240,11 @@ class XLADeployment(Deployment):
             hist.observe(s)
         lat = sum(samples) / n_runs
         energy = hw.energy_j(lat)
+        leaves = jax.tree.leaves(out)
+        dev = (next(iter(leaves[0].devices())) if leaves
+               and hasattr(leaves[0], "devices") else jax.devices()[0])
         return MeasurementReport(
-            model=model, platform="container-cpu(Elastic-Node proxy)",
+            model=model, platform=f"xla({dev.platform}:{dev.device_kind})",
             latency_s=lat, power_w=hw.active_w, energy_j=energy,
             gop_per_j=(model_flops / 1e9) / energy if energy else 0.0,
             n_runs=n_runs, target=self.target,

@@ -3,13 +3,22 @@
 The RTL emulator's original schedule dispatched one interpreted MAC
 ``pallas_call`` per timestep per cell and gathered the activation LUTs from
 host-side tables between dispatches. This kernel is the single-dispatch
-replacement, mirroring the f32 ``kernels/lstm_cell`` template: the fused gate
-matrix W ((d_in+hid) × 4·hid), the accumulator-scale bias, and *both*
-activation ROMs are pinned in VMEM for the whole window (BlockSpec maps them
-to the same block for every grid step), the int32 (h, c) state lives in VMEM
-scratch, and a ``fori_loop`` iterates the timesteps in-kernel — requant
-(round-half-even shift + saturate) and LUT gathers included. One dispatch per
-cell per window instead of ``seq_len``, zero intermediate HBM traffic.
+replacement, mirroring the f32 ``kernels/lstm_cell`` template: the gate
+matrix W ((d_in+hid) × 4·hid, split into its x and h rows) and the
+accumulator-scale bias are pinned in VMEM for the whole window, *both*
+activation ROMs sit in SMEM, the int32 (h, c) state lives in VMEM scratch,
+and a ``fori_loop`` iterates the timesteps in-kernel — requant
+(round-half-even shift + saturate) and ROM lookups included. One dispatch
+per cell per window instead of ``seq_len``, zero intermediate HBM traffic.
+
+Both pieces are written in the forms the TPU compiler accepts:
+
+* the gate MACs are int8×int8→int32 MXU matmuls over int8 limbs of the
+  operands (:func:`~repro.quant.fixedpoint.int_matmul`; one matmul per
+  operand pair for formats of at most 8 bits);
+* a ROM lookup is a compare-select sweep over the table's addresses, one
+  scalar SMEM read per address — exact for any int32 table word, where
+  a vector gather does not lower.
 
 Semantics are DESIGN.md §4, integer for integer — the same
 ``fxp_requant_int`` primitive as the per-step reference paths, so the
@@ -27,7 +36,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.quant.fixedpoint import FxpFormat, fxp_requant_int
+from repro.quant.fixedpoint import FxpFormat, fxp_requant_int, int_matmul
+
+#: ROM addresses compared per loop iteration (Mosaic unrolls the body)
+_ROM_UNROLL = 8
 
 
 @dataclass(frozen=True)
@@ -49,37 +61,51 @@ class CellSpec:
     tanh_lo: int                     # tanh ROM address offset
 
 
-def _lstm_int_kernel(x_ref, w_ref, b_ref, sig_ref, tanh_ref, o_ref,
+def rom_lookup(codes: jax.Array, rom_ref, lo: int) -> jax.Array:
+    """``rom[codes - lo]`` by compare-select over every ROM address.
+
+    ``rom_ref`` is a 1-D int32 ref (SMEM in the kernel). Codes are
+    saturated to the ROM's input format, so every address is in range.
+    """
+    depth = rom_ref.shape[0]
+    unroll = min(_ROM_UNROLL, depth)
+    idx = codes - lo
+
+    def body(j, acc):
+        for u in range(unroll):
+            k = j * unroll + u
+            acc = jnp.where(idx == k, rom_ref[k], acc)
+        return acc
+
+    return jax.lax.fori_loop(0, depth // unroll, body, jnp.zeros_like(idx))
+
+
+def _lstm_int_kernel(x_ref, wx_ref, wh_ref, b_ref, sig_ref, tanh_ref, o_ref,
                      h_ref, c_ref, *, spec: CellSpec):
     A, C = spec.act_fmt, spec.state_fmt
     af, wf, cf = A.frac_bits, spec.w_fmt.frac_bits, C.frac_bits
-    H, d_in = spec.hidden, spec.d_in
+    H = spec.hidden
+    bits = dict(x_bits=A.total_bits, w_bits=spec.w_fmt.total_bits)
     h_ref[...] = jnp.zeros_like(h_ref)
     c_ref[...] = jnp.zeros_like(c_ref)
-    w = w_ref[...]                                   # ((d_in+hid), 4*hid)
+    wx = wx_ref[...]                                 # (d_in, 4*hid)
+    wh = wh_ref[...]                                 # (hid, 4*hid)
     b = b_ref[...]                                   # (1, 4*hid)
-    sig_rom = sig_ref[0]                             # (2**A.bits,)
-    tanh_rom = tanh_ref[0]
 
     def step(t, _):
-        x_t = x_ref[:, t, :].astype(jnp.int32)       # (bb, d_in)
-        h = h_ref[...]
-        zx = jax.lax.dot_general(x_t, w[:d_in], (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.int32)
-        zh = jax.lax.dot_general(h, w[d_in:], (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.int32)
-        z = fxp_requant_int(zx + zh + b, af + wf, A)  # acc -> act fmt
-        i, f = z[:, :H], z[:, H:2 * H]
-        g, o = z[:, 2 * H:3 * H], z[:, 3 * H:]
-        si = jnp.take(sig_rom, i - spec.sig_lo)
-        sf = jnp.take(sig_rom, f - spec.sig_lo)
-        so = jnp.take(sig_rom, o - spec.sig_lo)
-        tg = jnp.take(tanh_rom, g - spec.tanh_lo)
+        x_t = x_ref[:, t, :]                         # (bb, d_in)
+        acc = (int_matmul(x_t, wx, **bits) + int_matmul(h_ref[...], wh, **bits)
+               + b)
+        z = fxp_requant_int(acc, af + wf, A)         # acc -> act fmt
+        sz = rom_lookup(z, sig_ref, spec.sig_lo)     # every gate, one sweep
+        tz = rom_lookup(z, tanh_ref, spec.tanh_lo)
+        si, sf, so = sz[:, :H], sz[:, H:2 * H], sz[:, 3 * H:]
+        tg = tz[:, 2 * H:3 * H]
         # align si*tg (scale 2·af) to sf*c (scale af+cf): << (cf - af)
         term = sf * c_ref[...] + jax.lax.shift_left(si * tg, cf - af)
         c = fxp_requant_int(term, af + cf, C)
         c_a = fxp_requant_int(c, cf, A)
-        tc = jnp.take(tanh_rom, c_a - spec.tanh_lo)
+        tc = rom_lookup(c_a, tanh_ref, spec.tanh_lo)
         h = fxp_requant_int(so * tc, 2 * af, A)
         h_ref[...] = h
         c_ref[...] = c
@@ -95,7 +121,7 @@ def lstm_window_int_pallas(
     b: jax.Array,           # (4*hidden,) int32, accumulator scale
     sig_table: jax.Array,   # (2**act_bits,) int32 ROM
     tanh_table: jax.Array,  # (2**act_bits,) int32 ROM
-    *, spec: CellSpec, block_b: int = 128, interpret: bool = False,
+    *, spec: CellSpec, block_b: int, interpret: bool,
 ) -> jax.Array:
     """Returns the full hidden sequence (B, S, hidden) int32."""
     B, S, d_in = x.shape
@@ -103,16 +129,17 @@ def lstm_window_int_pallas(
     H = spec.hidden
     bb = min(block_b, B)
     assert B % bb == 0, (B, bb)
-    depth = sig_table.shape[0]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(_lstm_int_kernel, spec=spec),
         grid=(B // bb,),
         in_specs=[
             pl.BlockSpec((bb, S, d_in), lambda i: (i, 0, 0)),
-            pl.BlockSpec(w.shape, lambda i: (0, 0)),      # VMEM-resident
-            pl.BlockSpec((1, b.shape[0]), lambda i: (0, 0)),
-            pl.BlockSpec((1, depth), lambda i: (0, 0)),
-            pl.BlockSpec((1, tanh_table.shape[0]), lambda i: (0, 0)),
+            pl.BlockSpec((d_in, 4 * H), lambda i: (0, 0)),  # VMEM-resident
+            pl.BlockSpec((H, 4 * H), lambda i: (0, 0)),
+            pl.BlockSpec((1, 4 * H), lambda i: (0, 0)),
+            smem,
+            smem,
         ],
         out_specs=pl.BlockSpec((bb, S, H), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H), jnp.int32),
@@ -121,5 +148,5 @@ def lstm_window_int_pallas(
             pltpu.VMEM((bb, H), jnp.int32),
         ],
         interpret=interpret,
-    )(x, w, b.reshape(1, -1), sig_table.reshape(1, -1),
-      tanh_table.reshape(1, -1))
+    )(x.astype(jnp.int32), w[:d_in], w[d_in:], b.reshape(1, -1), sig_table,
+      tanh_table)

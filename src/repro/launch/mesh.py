@@ -35,7 +35,13 @@ def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
 
 
 def make_smoke_mesh(shape=(1, 1), axes=("data", "model")):
+    """A mesh over the first ``prod(shape)`` devices; raises when the host
+    has fewer, never builds a smaller mesh."""
     import numpy as np
 
-    devices = jax.devices()[: shape[0] * shape[1]]
-    return jax.sharding.Mesh(np.asarray(devices).reshape(shape), axes)
+    n = shape[0] * shape[1]
+    devices = jax.devices()
+    if len(devices) < n:
+        raise RuntimeError(f"mesh {tuple(shape)} needs {n} devices but "
+                           f"only {len(devices)} present")
+    return jax.sharding.Mesh(np.asarray(devices[:n]).reshape(shape), axes)

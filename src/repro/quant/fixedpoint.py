@@ -90,6 +90,48 @@ def fxp_requant_int(v: jax.Array, from_frac: int, fmt: FxpFormat) -> jax.Array:
     return jnp.clip(q, fmt.lo, fmt.hi)
 
 
+def int8_limbs(v: jax.Array, bits: int) -> list:
+    """Split signed ``bits``-wide integer codes into int8 limbs.
+
+    ``v == sum(limb[j] << 7*j)``: the low limbs are 7-bit unsigned slices,
+    the top limb is the signed remainder, which fits int8 whenever ``v``
+    lies in its format's range. One limb for ``bits <= 8``.
+    """
+    n = 1 if bits <= 8 else 1 + -(-(bits - 8) // 7)
+    v = v.astype(jnp.int32)
+    limbs = [(jax.lax.shift_right_arithmetic(v, 7 * j) & 0x7F)
+             .astype(jnp.int8) for j in range(n - 1)]
+    limbs.append(jax.lax.shift_right_arithmetic(v, 7 * (n - 1))
+                 .astype(jnp.int8))
+    return limbs
+
+
+def int_matmul(x: jax.Array, w: jax.Array, *, x_bits: int,
+               w_bits: int) -> jax.Array:
+    """Exact ``x @ w`` of integer codes, accumulated in int32.
+
+    Both operands are split into int8 limbs (:func:`int8_limbs`) and every
+    limb pair is one int8×int8→int32 matmul — the form the TPU's MXU takes
+    (it refuses int32×int32) — recombined by left shifts. Operands of at
+    most 8 bits are one matmul. The result equals the int32 matmul
+    whenever the operands lie in their formats' ranges; int32 wraparound is
+    modular, so shifted partial sums may overflow as long as the final
+    accumulator fits, which the §4 envelope guarantees. A word outside its
+    format (an SEU-flipped high bit) is read by its low ``7·(limbs-1)+8``
+    bits, so on a corrupted design this can differ from the int32 matmul;
+    both then differ from the golden response.
+    """
+    acc = None
+    for i, xl in enumerate(int8_limbs(x, x_bits)):
+        for j, wl in enumerate(int8_limbs(w, w_bits)):
+            p = jax.lax.dot_general(xl, wl, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.int32)
+            if i + j:
+                p = jax.lax.shift_left(p, 7 * (i + j))
+            acc = p if acc is None else acc + p
+    return acc
+
+
 @jax.custom_vjp
 def fxp_fake_quant(x: jax.Array, scale: jax.Array, lo: float, hi: float):
     q = jnp.clip(jnp.round(x * scale), lo, hi)

@@ -241,6 +241,8 @@ class GuardedDeployment(Deployment):
                                       metrics=self.metrics)
         self.calls = 0
         self.detections: List[dict] = []
+        self.last_error: Optional[str] = None  # newest primary exception
+        self.canary_row = 0              # first golden row of the next probe
 
     # -- Deployment contract -------------------------------------------- #
     @property
@@ -271,11 +273,12 @@ class GuardedDeployment(Deployment):
 
     # -- health --------------------------------------------------------- #
     def probe(self) -> Optional[bool]:
-        """Run the canary now: replay ``canary_slice`` golden rows through
-        the primary and demand integer-exact responses. A mismatch is a
-        detected silent fault — counter, detection log entry, breaker
-        tripped with quarantine. Returns the verdict (None without a
-        canary set)."""
+        """Run the canary now: replay the next ``canary_slice`` golden rows
+        through the primary and demand integer-exact responses. Each probe
+        starts where the last one stopped, so successive probes sweep the
+        whole golden set. A mismatch is a detected silent fault — counter,
+        detection log entry, breaker tripped with quarantine. Returns the
+        verdict (None without a canary set)."""
         if self.canary_vectors is None:
             return None
         from repro.verify import canary_check
@@ -284,7 +287,10 @@ class GuardedDeployment(Deployment):
         with trc.span("resilience.canary", guard=self.name,
                       n=self.policy.canary_slice):
             res = canary_check(self.primary, self.canary_vectors,
-                               n=self.policy.canary_slice)
+                               n=self.policy.canary_slice,
+                               start=self.canary_row)
+        self.canary_row = ((self.canary_row + self.policy.canary_slice)
+                           % self.canary_vectors.n_vectors)
         self.metrics.counter("resilience.canary_probes").inc()
         if not res.passed:
             self.metrics.counter("resilience.faults_detected").inc()
@@ -329,8 +335,9 @@ class GuardedDeployment(Deployment):
         try:
             out = self.primary(*args)
             jax.block_until_ready(out)
-        except Exception:                # noqa: BLE001 - any call failure
+        except Exception as e:           # noqa: BLE001 - any call failure
             self.metrics.counter("resilience.primary_errors").inc()
+            self.last_error = f"{type(e).__name__}: {e}"
             return False, None
         if self.clock() - t0 > self.policy.timeout_s:
             self.metrics.counter("resilience.timeouts").inc()
@@ -398,7 +405,9 @@ class GuardedDeployment(Deployment):
             f"guarded deployment {self.name!r}: primary unavailable "
             f"(breaker {self.breaker.state}"
             f"{', quarantined' if self.breaker.quarantined else ''}, "
-            f"{retries} retries) and no fallback answered")
+            f"{retries} retries) and no fallback answered"
+            + (f"; last primary error: {self.last_error}"
+               if self.last_error else ""))
 
     def __call__(self, *args):
         return self.call(*args).value

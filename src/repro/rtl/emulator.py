@@ -243,6 +243,14 @@ class RTLEmulator:
                 mx.counter("rtl.emulator.cache_evict").inc(evicted)
         return prog, hit
 
+    def lower(self, x_int, params: Optional[Dict[str, Dict]] = None):
+        """Lower the compiled graph walk for input ``x_int`` — an array or
+        a ``jax.ShapeDtypeStruct`` — with this emulator's params (or the
+        given pytree of the same structure). ``.compile().as_text()`` of
+        the result is the program a dispatch of that shape runs."""
+        prog, _ = self._program(x_int.shape, x_int.dtype)
+        return prog.lower(x_int, self.params() if params is None else params)
+
     def has_program(self, shape, dtype) -> bool:
         """Whether the LRU already holds a compiled program for this
         input — the serving router's affinity probe

@@ -17,7 +17,8 @@ multi-emulation tests and :func:`repro.verify.conformance.run_conformance_batch`
 On a multi-device host (`XLA_FLAGS=--xla_force_host_platform_device_count`
 counts) ``shard=True`` additionally splits the design axis across a 1-D
 mesh with :func:`repro.shardmap.shard_map` — candidates are independent,
-so the partitioning is embarrassing.
+so the partitioning is embarrassing. It raises where the device count does
+not divide K rather than fall back to the unsharded vmap.
 """
 from __future__ import annotations
 
@@ -95,11 +96,12 @@ class MultiDesignEmulator:
         self.trace_count = 0
 
     def _design_mesh(self):
-        """A 1-D ``("design", "model")`` mesh when the host's devices
-        divide K; None (pure vmap) otherwise."""
+        """A 1-D ``("design", "model")`` mesh over every device of the
+        host; raises where the devices cannot split K evenly."""
         n = len(jax.devices())
-        if n <= 1 or self.k % n != 0:
-            return None
+        if self.k % n != 0:
+            raise ValueError(f"shard=True needs the device count to divide "
+                             f"K: {n} devices, K={self.k}")
         from repro.launch.mesh import make_smoke_mesh
 
         return make_smoke_mesh(shape=(n, 1), axes=("design", "model"))

@@ -36,7 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.lstm_cell_int import CellSpec, lstm_window_int
-from repro.quant.fixedpoint import FxpFormat, fxp_quantize, fxp_requant_int
+from repro.quant.fixedpoint import (FxpFormat, fxp_quantize, fxp_requant_int,
+                                    int_matmul)
 from repro.quant.qat import hard_sigmoid, hard_tanh
 from repro.rtl import templates as T
 from repro.rtl.analyze import (AnalysisContext, Interval, check_lut_domain,
@@ -53,31 +54,51 @@ from repro.rtl.resources import (CONV_DSP, LINEAR_DSP, LSTM_DSP,
 # --------------------------------------------------------------------------- #
 
 
-def _mac_kernel(xh_ref, w_ref, b_ref, o_ref, *, shift: int, lo: int, hi: int):
-    acc = jax.lax.dot_general(
-        xh_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+def _mac_kernel(xh_ref, w_ref, b_ref, o_ref, *, shift: int, lo: int, hi: int,
+                x_bits: int, w_bits: int):
+    acc = int_matmul(xh_ref[...], w_ref[...], x_bits=x_bits, w_bits=w_bits)
     acc = acc + b_ref[...]
     # same requant primitive as the jnp path — one rounding implementation
     q = fxp_requant_int(acc, shift, FxpFormat(32, 0))
     o_ref[...] = jnp.clip(q, lo, hi)
 
 
-@functools.partial(jax.jit, static_argnames=("shift", "lo", "hi",
-                                             "interpret"))
+#: rows of the MAC operand per grid step (a multiple of the 8-row tile)
+MAC_BLOCK_ROWS = 512
+
+
+@functools.partial(jax.jit, static_argnames=("shift", "lo", "hi", "x_bits",
+                                             "w_bits", "interpret"))
 def mac_int_pallas(xh: jax.Array, w: jax.Array, b: jax.Array, *,
-                   shift: int, lo: int, hi: int,
-                   interpret: bool = True) -> jax.Array:
-    """(B, K) int32 @ (K, N) int32 + b, requantized: one template invocation."""
+                   shift: int, lo: int, hi: int, x_bits: int, w_bits: int,
+                   interpret: bool) -> jax.Array:
+    """(B, K) codes @ (K, N) codes + b, requantized: one template invocation.
+
+    ``x_bits``/``w_bits`` are the operands' format widths; they pick the
+    int8 limb split of :func:`~repro.quant.fixedpoint.int_matmul`. Rows
+    are tiled over a grid of ``MAC_BLOCK_ROWS`` (the weights and bias stay
+    resident), so the VMEM footprint is bounded at any batch.
+    """
     from jax.experimental import pallas as pl
 
-    B, _ = xh.shape
+    B, K = xh.shape
     N = w.shape[1]
-    return pl.pallas_call(
-        functools.partial(_mac_kernel, shift=shift, lo=lo, hi=hi),
-        out_shape=jax.ShapeDtypeStruct((B, N), jnp.int32),
+    bm = B if B <= MAC_BLOCK_ROWS else MAC_BLOCK_ROWS
+    pad = (-B) % bm
+    if pad:
+        xh = jnp.pad(xh, ((0, pad), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_mac_kernel, shift=shift, lo=lo, hi=hi,
+                          x_bits=x_bits, w_bits=w_bits),
+        grid=((B + pad) // bm,),
+        in_specs=[pl.BlockSpec((bm, K), lambda i: (i, 0)),
+                  pl.BlockSpec((K, N), lambda i: (0, 0)),
+                  pl.BlockSpec((1, N), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B + pad, N), jnp.int32),
         interpret=interpret,
-    )(xh, w, b.reshape(1, -1))
+    )(xh.astype(jnp.int32), w.astype(jnp.int32), b.reshape(1, -1))
+    return out[:B]
 
 
 def _mac_int_jnp(xh, w, b, *, shift, lo, hi):
@@ -87,11 +108,14 @@ def _mac_int_jnp(xh, w, b, *, shift, lo, hi):
 
 
 def mac_int(xh: jax.Array, w: jax.Array, b: jax.Array, *, shift: int,
-            fmt: FxpFormat, mode: str, interpret: bool) -> jax.Array:
-    """The shared serial-MAC schedule, on either execution substrate."""
+            fmt: FxpFormat, x_fmt: FxpFormat, w_fmt: FxpFormat, mode: str,
+            interpret: bool) -> jax.Array:
+    """The shared serial-MAC schedule, on either execution substrate.
+    ``x_fmt``/``w_fmt`` are the operands' formats, ``fmt`` the output's."""
     if mode == "jnp":
         return _mac_int_jnp(xh, w, b, shift=shift, lo=fmt.lo, hi=fmt.hi)
     return mac_int_pallas(xh, w, b, shift=shift, lo=fmt.lo, hi=fmt.hi,
+                          x_bits=x_fmt.total_bits, w_bits=w_fmt.total_bits,
                           interpret=interpret)
 
 
@@ -356,7 +380,8 @@ class LinearTemplate(HWTemplate):
         p = em.prepared(n.name)
         shift = requant_shift(n.in_fmt, n.w_fmt, n.out_fmt)
         env[n.outputs[0]] = mac_int(x, p["w"], p["b"], shift=shift,
-                                    fmt=n.out_fmt, mode=mode,
+                                    fmt=n.out_fmt, x_fmt=n.in_fmt,
+                                    w_fmt=n.w_fmt, mode=mode,
                                     interpret=em.interpret)
 
     def reference(self, n: LinearNode, env: Dict, luts: Dict) -> None:
@@ -485,7 +510,8 @@ class LSTMCellTemplate(HWTemplate):
             seq = lstm_window_int(
                 src.astype(jnp.int32), w, b,
                 em.prepared(n.sigmoid_lut)["table"],
-                em.prepared(n.tanh_lut)["table"], spec=p["spec"])
+                em.prepared(n.tanh_lut)["table"], spec=p["spec"],
+                interpret=em.interpret)
         else:
             B = src.shape[0]
             A, C = n.act_fmt, n.state_fmt
@@ -496,7 +522,8 @@ class LSTMCellTemplate(HWTemplate):
             for t in range(n.seq_len):
                 xh = jnp.concatenate([src[:, t].astype(jnp.int32), h],
                                      axis=-1)
-                z = mac_int(xh, w, b, shift=n.mac_shift, fmt=A, mode=mode,
+                z = mac_int(xh, w, b, shift=n.mac_shift, fmt=A, x_fmt=A,
+                            w_fmt=n.w_fmt, mode=mode,
                             interpret=em.interpret)
                 i, f, g, o = jnp.split(z, 4, axis=-1)
                 si = em.lookup(n.sigmoid_lut, i)
@@ -649,7 +676,8 @@ class Conv1dTemplate(HWTemplate):
         xh = self._frames(x, n).reshape(B * t_out, n.kernel * n.channels)
         shift = requant_shift(n.in_fmt, n.w_fmt, n.out_fmt)
         y = mac_int(xh, p["w_mat"], p["b"], shift=shift,
-                    fmt=n.out_fmt, mode=mode, interpret=em.interpret)
+                    fmt=n.out_fmt, x_fmt=n.in_fmt, w_fmt=n.w_fmt, mode=mode,
+                    interpret=em.interpret)
         env[n.outputs[0]] = y.reshape(B, t_out, n.channels)
 
     def reference(self, n: Conv1dNode, env: Dict, luts: Dict) -> None:

@@ -40,6 +40,13 @@ from repro.serving.queue import (DONE, EXPIRED, FAILED, AdmissionQueue,
 from repro.serving.router import AffinityRouter, NoServeableMember
 
 
+def _describe(e: BaseException) -> str:
+    """A failed request's ``error``: the exception's type and message (a
+    compiler refusal is only diagnosable from its text)."""
+    msg = str(e)
+    return f"{type(e).__name__}: {msg}" if msg else type(e).__name__
+
+
 @dataclass(frozen=True)
 class FarmConfig:
     """The farm's knobs, one validated frozen dataclass."""
@@ -255,7 +262,7 @@ class AcceleratorFarm:
                 idx, member, hit = router.route(arr.shape, arr.dtype,
                                                 exclude=tried)
             except NoServeableMember as e:
-                return self._fail(batch, type(e).__name__)
+                return self._fail(batch, _describe(e))
             try:
                 with trc.span("serving.dispatch", design=batch.design,
                               bucket=batch.bucket_len,
@@ -270,7 +277,7 @@ class AcceleratorFarm:
                 tried = tried + (idx,)
                 mx.counter("serving.redispatches").inc()
                 if attempt == 1:
-                    return self._fail(batch, type(e).__name__)
+                    return self._fail(batch, _describe(e))
                 continue
             now = self.clock()
             mx.counter("serving.dispatches").inc()

@@ -242,7 +242,7 @@ def _report(farm: AcceleratorFarm, pools: Sequence[DesignPool],
     }
 
 
-def main(argv: Optional[Sequence[str]] = None) -> int:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     p = argparse.ArgumentParser(
         prog="python -m repro.serving.loadgen",
         description="seeded mixed-traffic loadgen for the accelerator farm")
@@ -267,25 +267,30 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                    help="run the tape once unreported first so every "
                         "(B, L, F) program is compiled — the reported "
                         "pass then measures steady state, not compiles")
-    args = p.parse_args(argv)
+    return p.parse_args(argv)
 
+
+def serve(args: argparse.Namespace
+          ) -> Tuple[dict, AcceleratorFarm, List[DesignPool]]:
+    """Build the farm ``args`` describe and drive its tape; returns the
+    report plus the farm and pools of the reported (batched) run."""
     archs = tuple(a.strip() for a in args.arch.split(",") if a.strip())
     spec = TrafficSpec(archs=archs, n_requests=args.requests,
                        wave=args.wave, mode=args.mode, seed=args.seed,
                        timeout_s=args.timeout_s)
 
-    def one_run(max_batch: int, pad_batch: bool) -> dict:
+    def one_run(max_batch: int, pad_batch: bool):
         farm, pools = build_farm(
             archs, replicas=args.replicas, seed=args.seed,
             cfg=FarmConfig(max_batch=max_batch, pad_batch=pad_batch),
             metrics=MetricsRegistry())
         if args.warm:                # compile pass; its requests unreported
             run_loadgen(farm, pools, spec)
-        return run_loadgen(farm, pools, spec)
+        return run_loadgen(farm, pools, spec), farm, pools
 
-    report = one_run(args.max_batch, True)
+    report, farm, pools = one_run(args.max_batch, True)
     if args.baseline:
-        base = one_run(1, False)
+        base, _, _ = one_run(1, False)
         report["unbatched"] = {
             "throughput_windows_per_s": base["throughput_windows_per_s"],
             "latency_p99_s": base["latency_p99_s"],
@@ -294,7 +299,32 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                            base["throughput_windows_per_s"])
         report["batching_speedup"] = (tput / base_tput
                                       if tput and base_tput else None)
+    return report, farm, pools
 
+
+def failures(report: dict, args: argparse.Namespace) -> List[str]:
+    """Why the run fails the serving gate (empty when it passes): requests
+    dropped after admission, failed requests, or a blown p99 bound."""
+    out = []
+    if report["dropped_after_admission"] != 0:
+        out.append(f"{report['dropped_after_admission']} requests dropped "
+                   "after admission")
+    failed = report["by_status"].get("failed", 0)
+    if failed != 0:
+        out.append(f"{failed} requests failed")
+    if (args.p99_bound is not None
+            and report["latency_p99_s"] > args.p99_bound):
+        out.append(f"p99 latency {report['latency_p99_s']:.4f}s exceeds "
+                   f"bound {args.p99_bound}s")
+    return out
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    from repro.launch.cache import enable_compile_cache
+
+    args = parse_args(argv)
+    enable_compile_cache()
+    report, _, _ = serve(args)
     text = json.dumps(report, indent=2, sort_keys=True)
     if args.out:
         with open(args.out, "w", encoding="utf-8") as f:
@@ -314,21 +344,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if report.get("batching_speedup") is not None:
         print(f"  batching speedup vs unbatched: "
               f"{report['batching_speedup']:.1f}x")
-
-    ok = True
-    if report["dropped_after_admission"] != 0:
-        print(f"FAIL: {report['dropped_after_admission']} requests "
-              "dropped after admission", file=sys.stderr)
-        ok = False
-    if st.get("failed", 0) != 0:
-        print(f"FAIL: {st['failed']} requests failed", file=sys.stderr)
-        ok = False
-    if (args.p99_bound is not None
-            and report["latency_p99_s"] > args.p99_bound):
-        print(f"FAIL: p99 latency {report['latency_p99_s']:.4f}s exceeds "
-              f"bound {args.p99_bound}s", file=sys.stderr)
-        ok = False
-    return 0 if ok else 1
+    fails = failures(report, args)
+    for msg in fails:
+        print(f"FAIL: {msg}", file=sys.stderr)
+    return 1 if fails else 0
 
 
 if __name__ == "__main__":

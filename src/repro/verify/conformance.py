@@ -311,20 +311,22 @@ class CanaryResult:
         return asdict(self)
 
 
-def canary_check(dep, vectors: VectorSet, *, n: int = 4) -> CanaryResult:
-    """Replay the first ``n`` golden rows through a *live* deployment and
-    demand integer-exact responses — the in-service slice of the Elastic
-    Node protocol that ``repro.resilience`` guards probe with.
+def canary_check(dep, vectors: VectorSet, *, n: int = 4,
+                 start: int = 0) -> CanaryResult:
+    """Replay ``n`` golden rows from row ``start`` (wrapping) through a
+    *live* deployment and demand integer-exact responses — the in-service
+    slice of the Elastic Node protocol that ``repro.resilience`` guards
+    probe with, moving ``start`` on from probe to probe.
 
     Unlike :func:`run_conformance` (which re-executes the *design*), this
     exercises the deployment instance actually serving traffic: for RTL
     deployments the int codes go straight through its emulator (whose
     prepared memories are exactly what an SEU corrupts); host-executed
     deployments answer in float and are re-encoded at the output format.
-    A single flipped weight bit shows up here as a code mismatch on the
-    rail rows long before any accuracy metric would move.
+    A single flipped weight bit shows up here as a code mismatch long
+    before any accuracy metric would move.
     """
-    vs = vectors.head(n)
+    vs = vectors.window(start, n)
     emu = getattr(dep, "emulator", None)
     if emu is not None:
         got = np.asarray(emu.run_int(vs.stimulus).outputs, np.int64)

@@ -73,22 +73,34 @@ class VectorSet:
         return self.stimulus.astype(np.float32) / self.in_fmt.scale
 
     def head(self, n: int) -> "VectorSet":
-        """The first ``n`` rows as a standalone set — the canary slice.
+        """The first ``n`` rows as a standalone set: the corner patterns
+        (zero, rail-low, rail-high) and then seeded random rows."""
+        vs = self.window(0, n)
+        vs.meta["slice"] = f"head({vs.n_vectors})"
+        return vs
+
+    def window(self, start: int, n: int) -> "VectorSet":
+        """``n`` consecutive rows from ``start``, wrapping past the end —
+        the canary slice.
 
         Health probes (``repro.resilience``) replay a handful of golden
-        rows per check; the leading rows are the corner patterns
-        (zero, rail-low, rail-high), which exercise every memory's
-        contribution before any random row would.
+        rows per check and move the window on each time, so successive
+        probes cover the whole set. A fixed slice would not do: the rail
+        rows saturate the gates, so a corrupted weight can leave them (and
+        any few fixed rows) unchanged while other inputs go wrong.
         """
         if n < 1:
-            raise ValueError(f"head(n) needs n >= 1, got {n}")
+            raise ValueError(f"window needs n >= 1, got {n}")
         n = min(n, self.n_vectors)
+        rows = (start + np.arange(n)) % self.n_vectors
         return VectorSet(design=self.design,
-                         stimulus=self.stimulus[:n],
-                         response=self.response[:n],
+                         stimulus=self.stimulus[rows],
+                         response=self.response[rows],
                          in_fmt=self.in_fmt, out_fmt=self.out_fmt,
                          seed=self.seed,
-                         meta={**self.meta, "slice": f"head({n})"})
+                         meta={**self.meta,
+                               "slice": f"window({start % self.n_vectors}, "
+                                        f"{n})"})
 
 
 def _sha256(a: np.ndarray) -> str:

@@ -7,10 +7,8 @@ import textwrap
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 # Every test here runs compressed_psum through shard_map. The subprocess
-# bodies import ``repro.shardmap.shard_map`` — the repo-wide compat wrapper
-# that resolves to ``jax.shard_map`` on current jax and to
-# ``jax.experimental.shard_map`` (auto=/check_rep= spellings) on 0.4.x — so
-# the suite runs for real on either generation instead of version-skipping.
+# bodies import ``repro.shardmap.shard_map``, the repo's one wrapper over
+# ``jax.shard_map``.
 
 
 def run_sub(body: str, n_dev: int = 8, timeout: int = 900) -> str:

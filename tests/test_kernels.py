@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.kernels import use_interpret
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.lstm_cell.ops import lstm_window
@@ -106,14 +107,18 @@ def test_template_registry_matches_packages():
 
 
 # --------------------------------------------------------------------------
+@pytest.mark.parametrize("fmts", [
+    (FxpFormat(8, 4), FxpFormat(8, 6), FxpFormat(16, 8)),   # the defaults
+    (FxpFormat(9, 4), FxpFormat(12, 9), FxpFormat(16, 8)),  # §4 envelope edge
+], ids=["act8-w8", "act9-w12"])
 @pytest.mark.parametrize("shape", [(1, 6, 1, 20), (7, 6, 3, 16),
                                    (64, 4, 2, 8), (200, 6, 1, 20)])
-def test_lstm_window_int(shape):
+def test_lstm_window_int(shape, fmts):
     """Fused integer window vs the per-step oracle: EXACT int equality."""
     import numpy as np
 
     B, S, din, hid = shape
-    A, W, C = FxpFormat(8, 4), FxpFormat(8, 6), FxpFormat(16, 8)
+    A, W, C = fmts
     spec = CellSpec(seq_len=S, d_in=din, hidden=hid, act_fmt=A, state_fmt=C,
                     w_fmt=W, sig_lo=A.lo, tanh_lo=A.lo)
     rng = np.random.default_rng(B + S)
@@ -125,7 +130,8 @@ def test_lstm_window_int(shape):
     depth = 2 ** A.total_bits
     sig = jnp.asarray(rng.integers(A.lo, A.hi + 1, depth), jnp.int32)
     tanh = jnp.asarray(rng.integers(A.lo, A.hi + 1, depth), jnp.int32)
-    y_k = lstm_window_int(x, w, b, sig, tanh, spec=spec)
+    y_k = lstm_window_int(x, w, b, sig, tanh, spec=spec,
+                          interpret=use_interpret())
     y_r = lstm_window_int_ref(x, w, b, sig, tanh, spec=spec)
     assert y_k.dtype == jnp.int32 and y_k.shape == (B, S, hid)
     assert np.array_equal(np.asarray(y_k), np.asarray(y_r))

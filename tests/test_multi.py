@@ -194,6 +194,58 @@ def test_run_conformance_batch_cross_checks_every_design():
         assert vs and all(v == 0 for v in vs.values())
 
 
+def test_sharded_design_axis_on_four_devices():
+    """shard=True on 4 forced host devices: the design axis splits over a
+    mesh of 4 distinct devices and stays integer-equal to the unsharded
+    vmap; a K the devices cannot split raises instead of falling back
+    (subprocess, so this process keeps seeing 1 device)."""
+    import subprocess
+    import sys
+    import textwrap
+
+    root = str(pathlib.Path(__file__).resolve().parents[1])
+    code = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        import sys
+        sys.path.insert(0, {root + "/src"!r})
+        import numpy as np
+        from repro.rtl import MultiDesignEmulator
+        from repro.verify.vectors import canonical_graph, generate_vectors
+
+        graphs = [canonical_graph("elastic-lstm", seed=s)[0]
+                  for s in range(8)]
+        stim = generate_vectors(graphs[0]).stimulus
+        sharded = MultiDesignEmulator(graphs, shard=True)
+        devs = list(sharded.mesh.devices.flat)
+        assert len({{d.id for d in devs}}) == 4, devs
+        a = np.asarray(sharded.run_int(stim).outputs)
+        b = np.asarray(MultiDesignEmulator(graphs).run_int(stim).outputs)
+        assert np.array_equal(a, b)
+        try:
+            MultiDesignEmulator(graphs[:3], shard=True)
+        except ValueError as e:
+            assert "divide" in str(e), e
+        else:
+            raise AssertionError("K=3 on 4 devices did not raise")
+        print("sharded-dse-ok")
+    """)
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600)
+    assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
+    assert "sharded-dse-ok" in r.stdout
+
+
+def test_smoke_mesh_refuses_missing_devices():
+    import jax
+
+    from repro.launch.mesh import make_smoke_mesh
+
+    with pytest.raises(RuntimeError, match="needs"):
+        make_smoke_mesh(shape=(len(jax.devices()) + 1, 1))
+
+
 # ---------------------------------------------------------------------------
 # satellite: experiments/hillclimb.py must not mutate XLA_FLAGS at import
 # ---------------------------------------------------------------------------

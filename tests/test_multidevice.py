@@ -7,9 +7,8 @@ import textwrap
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
-# The MoE EP paths go through ``repro.shardmap.shard_map`` — the repo-wide
-# compat wrapper over ``jax.shard_map`` / ``jax.experimental.shard_map`` —
-# so they run for real on either jax generation (no version skip).
+# The MoE EP paths go through ``repro.shardmap.shard_map``, the repo's one
+# wrapper over ``jax.shard_map``.
 
 
 def run_sub(body: str, n_dev: int = 8, timeout: int = 900) -> str:

@@ -430,6 +430,36 @@ def test_vectorset_head_slice(lstm_vectors):
         lstm_vectors.head(0)
 
 
+def test_vectorset_window_wraps(lstm_vectors):
+    n = lstm_vectors.n_vectors
+    w = lstm_vectors.window(n - 2, 4)
+    rows = [n - 2, n - 1, 0, 1]
+    assert np.array_equal(w.stimulus, lstm_vectors.stimulus[rows])
+    assert np.array_equal(w.response, lstm_vectors.response[rows])
+    assert w.meta["slice"] == f"window({n - 2}, 4)"
+    with pytest.raises(ValueError):
+        lstm_vectors.window(0, 0)
+
+
+def test_canary_probes_sweep_the_golden_set(lstm_graph, lstm_vectors):
+    """Successive probes replay successive slices, so a sweep covers every
+    golden row: a fault the sweep misses is one the full set misses."""
+    n = lstm_vectors.n_vectors
+    guard = GuardedDeployment(_rtl_dep(lstm_graph),
+                              policy=GuardPolicy(canary_slice=4),
+                              canary=lstm_vectors,
+                              metrics=MetricsRegistry())
+    starts = []
+    for _ in range(n // 4):
+        starts.append(guard.canary_row)
+        assert guard.probe()
+    assert starts == list(range(0, n, 4)) and guard.canary_row == 0
+    guard.primary.emulator.flip_bit("lstm_cell_l0", "w", 0, 7)
+    full = canary_check(guard.primary, lstm_vectors, n=n).passed
+    sweep = [guard.probe() for _ in range(n // 4)]
+    assert all(sweep) == full
+
+
 def test_canary_check_float_path(lstm_graph, lstm_vectors):
     """Host-executed deployments answer in float; the canary re-encodes at
     the output format and still demands integer-exact codes."""

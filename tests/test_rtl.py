@@ -210,12 +210,27 @@ def test_validate_formats_rejects_overflow_risk():
 # --------------------------------------------------------------------------- #
 
 
+#: Q-formats at the edges of the §4 envelope (DESIGN.md §4): 12-bit
+#: weights (two int8 limbs in the kernels' MXU matmuls) and 9-bit
+#: activations (512-word ROMs), each within the int32/f32 exactness bound
+#: at two stacked cells
+EDGE_FMTS = {
+    "default": {},
+    "w12": dict(w_fmt=FxpFormat(12, 9), act_fmt=FxpFormat(8, 4),
+                state_fmt=FxpFormat(16, 8)),
+    "act9": dict(w_fmt=FxpFormat(8, 6), act_fmt=FxpFormat(9, 4),
+                 state_fmt=FxpFormat(16, 8)),
+}
+
+
+@pytest.mark.parametrize("fmts", sorted(EDGE_FMTS))
 @pytest.mark.parametrize("mode", ["fused", "pallas", "jnp"])
 @pytest.mark.parametrize("batch", [1, 7, 64])
 @pytest.mark.parametrize("n_layers", [1, 2])
-def test_emulator_bit_exact_all_paths(mode, batch, n_layers):
-    """Every execution path × batch size × stacked depth, exact equality."""
-    g = _lstm_graph(n_layers=n_layers)
+def test_emulator_bit_exact_all_paths(mode, batch, n_layers, fmts):
+    """Every execution path × batch size × stacked depth × envelope-edge
+    format, exact equality."""
+    g = _lstm_graph(n_layers=n_layers, **EDGE_FMTS[fmts])
     x = jax.random.normal(jax.random.PRNGKey(10 * batch + n_layers),
                           (batch, 6, 1)) * 2.0
     assert_bit_exact(g, x, mode=mode)
@@ -570,10 +585,12 @@ def test_custom_template_round_trips():
 # --------------------------------------------------------------------------- #
 
 
+@pytest.mark.parametrize("fmts", ["default", "w12-act9"])
 @pytest.mark.parametrize("mode", ["fused", "pallas", "jnp"])
 @pytest.mark.parametrize("batch", [1, 5])
-def test_conv1d_bit_exact_all_paths(mode, batch):
-    g, cfg, _ = _conv_graph()
+def test_conv1d_bit_exact_all_paths(mode, batch, fmts):
+    edge = dict(w_fmt=FxpFormat(12, 9), act_fmt=FxpFormat(9, 4))
+    g, cfg, _ = _conv_graph(**(edge if fmts == "w12-act9" else {}))
     c = cfg.conv1d
     x = jax.random.normal(jax.random.PRNGKey(3 * batch),
                           (batch, c.seq_len, c.channels)) * 2.0

@@ -358,7 +358,7 @@ def test_farm_redispatches_once_around_a_failing_member():
     rid = farm.submit("fake", _win(4))
     farm.run_until_drained()
     assert farm.result(rid).status == "failed"
-    assert farm.result(rid).error == "RuntimeError"
+    assert farm.result(rid).error == "RuntimeError: member down"
     assert farm.stats().failed == 1
 
 
@@ -467,9 +467,12 @@ def test_loadgen_open_loop_sheds_under_overload():
     assert total == rep["submitted"] == 64
 
 
-def test_loadgen_cli_smoke(tmp_path):
+def test_loadgen_cli_smoke(tmp_path, monkeypatch):
     from repro.serving.loadgen import main
 
+    # main() turns on the persistent compile cache unless this variable is
+    # set; set, it leaves this process's JAX config alone
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     out = tmp_path / "bench.json"
     rc = main(["--arch", "lstm", "--requests", "16", "--wave", "8",
                "--replicas", "1", "--max-batch", "8",
