@@ -148,5 +148,6 @@ def lstm_window_int_pallas(
             pltpu.VMEM((bb, H), jnp.int32),
         ],
         interpret=interpret,
+        name="lstm_window_int",
     )(x.astype(jnp.int32), w[:d_in], w[d_in:], b.reshape(1, -1), sig_table,
       tanh_table)

@@ -16,6 +16,13 @@ Design contract (DESIGN.md §11):
   function call and an attribute load, nothing else. Hot loops may hoist
   the check themselves (``if trc.enabled: ...``) to skip even the kwargs
   dict.
+* **the profiler's clock on request** — ``Tracer(profiler=True)`` writes
+  each span as a ``jax.profiler.TraceAnnotation`` of the same name, its
+  attributes as the annotation's metadata. Under a running
+  ``jax.profiler`` trace the span then lands in the XSpace's host plane,
+  on the clock the device operations are stamped with, so a device idle
+  gap can be put down to the span the host was in. Nothing is kept in
+  memory in this mode: the XSpace is the store.
 * **deterministic span trees in tests** — the clock is injectable
   (``Tracer(clock=...)``), so tests drive a fake counter and assert exact
   start/end/parentage.
@@ -26,8 +33,7 @@ Design contract (DESIGN.md §11):
 Exporters: :func:`to_chrome_trace` emits Chrome trace-event JSON (the
 ``{"traceEvents": [...]}`` envelope, ``ph:"X"`` complete events with µs
 timestamps) viewable in Perfetto / ``chrome://tracing``;
-:func:`to_jsonl` emits one JSON object per span for line-oriented tooling;
-:func:`from_chrome_trace` parses the Chrome form back (round-trip tested).
+:func:`to_jsonl` emits one JSON object per span for line-oriented tooling.
 """
 from __future__ import annotations
 
@@ -38,7 +44,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional
 
 __all__ = [
     "Span", "Tracer", "get_tracer", "set_tracer", "span",
-    "to_chrome_trace", "to_jsonl", "from_chrome_trace",
+    "to_chrome_trace", "to_jsonl",
     "span_tree", "find_spans",
 ]
 
@@ -115,38 +121,63 @@ class _ActiveSpan:
         self.attrs.update(attrs)
 
 
+class _ProfilerSpan:
+    """A span written to the JAX profiler's trace; created by
+    :meth:`Tracer.span` in profiler mode. The profiler stamps it on its own
+    clock, and nests it by time on the thread that opened it."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, annotation):
+        self._annotation = annotation
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._annotation.__exit__(*exc)
+        return False
+
+    def set_attrs(self, **attrs) -> None:
+        """Attach values discovered mid-span, as annotation metadata."""
+        self._annotation.set_metadata(**attrs)
+
+
 class Tracer:
     """Collects spans. ``enabled=False`` makes every call a no-op.
 
     ``clock`` must be monotonic; it defaults to :func:`time.perf_counter`
-    and is injectable for deterministic tests.
+    and is injectable for deterministic tests. ``profiler=True`` writes
+    every span to the JAX profiler's trace instead (a
+    ``jax.profiler.TraceAnnotation`` under the span's name, its attributes
+    as metadata) and keeps nothing in ``spans``.
     """
 
-    __slots__ = ("enabled", "clock", "spans", "_stack", "_next_id")
+    __slots__ = ("enabled", "clock", "spans", "_stack", "_next_id",
+                 "_annotation")
 
     def __init__(self, enabled: bool = True,
-                 clock: Callable[[], float] = time.perf_counter):
+                 clock: Callable[[], float] = time.perf_counter,
+                 profiler: bool = False):
         self.enabled = enabled
         self.clock = clock
         self.spans: List[Span] = []          # finished, in completion order
         self._stack: List[_ActiveSpan] = []
         self._next_id = 1
+        self._annotation = None
+        if profiler:
+            from jax.profiler import TraceAnnotation
+
+            self._annotation = TraceAnnotation
 
     def span(self, name: str, **attrs):
         """Context manager recording one nested span (no-op when disabled)."""
         if not self.enabled:                 # the one-attribute-check guard
             return _NULL_SPAN
+        if self._annotation is not None:
+            return _ProfilerSpan(self._annotation(name, **attrs))
         return _ActiveSpan(self, name, attrs)
-
-    def event(self, name: str, **attrs) -> None:
-        """A zero-duration instant (recorded as a 0-length span)."""
-        if not self.enabled:
-            return
-        now = self.clock()
-        parent = self._stack[-1].span_id if self._stack else None
-        self.spans.append(Span(name=name, start=now, end=now, attrs=attrs,
-                               span_id=self._next_id, parent_id=parent))
-        self._next_id += 1
 
     def reset(self) -> None:
         self.spans = []
@@ -210,22 +241,6 @@ def to_chrome_trace(spans: Iterable[Span], *, pid: int = 1,
             "pid": pid, "tid": tid, "args": args,
         })
     return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-def from_chrome_trace(doc: dict) -> List[Span]:
-    """Parse :func:`to_chrome_trace` output back into spans (µs → s)."""
-    spans = []
-    for ev in doc["traceEvents"]:
-        if ev.get("ph") != "X":
-            continue
-        args = dict(ev.get("args", {}))
-        span_id = args.pop("span_id", 0)
-        parent_id = args.pop("parent_id", None)
-        start = ev["ts"] / 1e6
-        spans.append(Span(name=ev["name"], start=start,
-                          end=start + ev["dur"] / 1e6, attrs=args,
-                          span_id=span_id, parent_id=parent_id))
-    return spans
 
 
 def to_jsonl(spans: Iterable[Span]) -> str:

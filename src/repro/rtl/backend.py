@@ -19,6 +19,7 @@ from repro.core.report import MeasurementReport, SynthesisReport
 from repro.core.target import DEFAULT_N_RUNS, Deployment, TargetOptions
 from repro.core.types import ModelConfig
 from repro.energy.hw import HWSpec, XC7S15
+from repro.obs import get_tracer
 from repro.quant.fixedpoint import FxpFormat
 from repro.rtl.analyze import AnalysisError, analyze_graph
 from repro.rtl.diagnostics import AnalysisReport
@@ -114,7 +115,11 @@ class RTLExecutable(Deployment):
         self.emulator = RTLEmulator(self.graph, mode=self.emulator_mode)
 
     def __call__(self, x: jax.Array) -> jax.Array:
-        return self.emulator.run(x).outputs_f
+        trc = get_tracer()
+        if not trc.enabled:                  # hoisted guard: skip the attrs
+            return self.emulator.run(x).outputs_f
+        with trc.span("rtl.call", batch=int(x.shape[0])):
+            return self.emulator.run(x).outputs_f
 
     def run_many(self, xs) -> list:
         """Batched-throughput entry: see :meth:`RTLEmulator.run_many`."""
@@ -153,7 +158,7 @@ class RTLExecutable(Deployment):
         """
         import time
 
-        from repro.obs import get_metrics, get_tracer, percentile
+        from repro.obs import get_metrics, percentile
 
         x = args[-1] if isinstance(args, (tuple, list)) else args
         hw = hw or self.hw
@@ -260,8 +265,6 @@ def translate_rtl(cfg: ModelConfig, params, *,
     ``"warn"`` surfaces them as a UserWarning, ``"off"`` skips the pass.
     """
     import warnings
-
-    from repro.obs import get_tracer
 
     if analyze not in _ANALYZE_MODES:
         raise ValueError(f"analyze must be one of {_ANALYZE_MODES}, "

@@ -318,10 +318,9 @@ class RTLEmulator:
     def _result(self, env: Dict[str, jax.Array]) -> EmulationResult:
         out_edge = self.graph.edges[self.graph.outputs[0]]
         y = env[self.graph.outputs[0]]
-        return EmulationResult(outputs=y,
-                               outputs_f=y.astype(jnp.float32)
-                               / out_edge.fmt.scale,
-                               trace=env)
+        with get_tracer().span("rtl.emulator.unpack"):
+            y_f = y.astype(jnp.float32) / out_edge.fmt.scale
+        return EmulationResult(outputs=y, outputs_f=y_f, trace=env)
 
     def _count_dispatch(self, mode: str) -> None:
         with self._lock:
@@ -345,8 +344,9 @@ class RTLEmulator:
 
     def run(self, x: jax.Array) -> EmulationResult:
         in_fmt = self.graph.edges[self.graph.inputs[0]].fmt
-        return self.run_int(
-            jnp.asarray(fxp_to_int(x, in_fmt), jnp.int32))
+        with get_tracer().span("rtl.emulator.quantize"):
+            x_int = jnp.asarray(fxp_to_int(x, in_fmt), jnp.int32)
+        return self.run_int(x_int)
 
     # -- batched-throughput entry -------------------------------------------
     def run_many(self, xs: Union[jax.Array, Sequence[jax.Array]]

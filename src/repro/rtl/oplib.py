@@ -97,6 +97,7 @@ def mac_int_pallas(xh: jax.Array, w: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec((bm, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B + pad, N), jnp.int32),
         interpret=interpret,
+        name="mac_int_pallas",
     )(xh.astype(jnp.int32), w.astype(jnp.int32), b.reshape(1, -1))
     return out[:B]
 

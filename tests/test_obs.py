@@ -1,16 +1,20 @@
 """Tier-1: the observability layer (repro.obs) — spans, metrics, artifacts.
 
 Everything runs on an injectable fake clock, so span trees and durations
-are exact, not flaky-wall-clock assertions.
+are exact, not flaky-wall-clock assertions; the profiler mode is read
+back from the XSpace a ``jax.profiler`` trace writes.
 """
+import glob
 import json
+import os
+import warnings
 
 import numpy as np
 import pytest
 
 from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry, RunTrace, Tracer,
-                       ancestors, capture, children_of, find_spans, from_chrome_trace,
-                       get_metrics, get_tracer, percentile, set_tracer, span_tree,
+                       ancestors, capture, children_of, find_spans, get_metrics,
+                       get_tracer, percentile, set_tracer, span_tree,
                        to_chrome_trace, to_jsonl)
 
 
@@ -77,20 +81,10 @@ def test_span_ids_unique_and_exception_safe():
     assert len(ids) == len(set(ids))
 
 
-def test_event_is_zero_duration_child():
-    trc = Tracer(clock=FakeClock())
-    with trc.span("root"):
-        trc.event("mark", k=1)
-    ev = find_spans(trc.spans, "mark")[0]
-    assert ev.duration == 0.0
-    assert ev.parent_id == find_spans(trc.spans, "root")[0].span_id
-
-
 def test_disabled_tracer_records_nothing():
     trc = Tracer(enabled=False)
     with trc.span("nope", big=list(range(100))) as s:
         s.set_attrs(more=1)          # null span swallows attrs
-    trc.event("also-nope")
     assert trc.spans == []
     # the disabled path hands back one shared object (no per-call alloc)
     assert trc.span("a") is trc.span("b")
@@ -167,7 +161,7 @@ def test_registry_get_or_create_and_snapshot():
 
 
 # --------------------------------------------------------------------------- #
-# Exporters: Chrome trace round-trip, JSONL
+# Exporters: Chrome trace, JSONL
 # --------------------------------------------------------------------------- #
 
 
@@ -187,15 +181,14 @@ def test_chrome_trace_schema_and_roundtrip():
         assert ev["ph"] == "X"
         assert ev["ts"] >= 0 and ev["dur"] >= 0           # µs, rebased
         assert {"name", "pid", "tid", "args"} <= set(ev)
-    back = from_chrome_trace(doc)
-    assert [(s.name, s.span_id, s.parent_id) for s in back] == \
-        [(s.name, s.span_id, s.parent_id) for s in spans]
-    for orig, rt in zip(spans, back):
-        assert rt.duration == pytest.approx(orig.duration, abs=1e-9)
-        assert rt.attrs == orig.attrs
-    # the tree survives the format
-    assert [(s.name, d) for s, d in span_tree(back)] == [
-        ("root", 0), ("child", 1)]
+    # the tree survives the JSON round trip: span/parent ids ride in args
+    by_name = {ev["name"]: ev for ev in doc["traceEvents"]}
+    for orig in spans:
+        ev = by_name[orig.name]
+        assert ev["dur"] == pytest.approx(orig.duration * 1e6)
+        assert ev["args"]["span_id"] == orig.span_id
+        assert ev["args"].get("parent_id") == orig.parent_id
+        assert {k: ev["args"][k] for k in orig.attrs} == orig.attrs
 
 
 def test_jsonl_one_object_per_span():
@@ -263,3 +256,106 @@ def test_runtrace_summary_depth_cap():
     rt = RunTrace(name="deep", spans=list(trc.spans))
     assert "lvl2" in rt.summary()
     assert "lvl2" not in rt.summary(max_depth=1)
+
+
+# --------------------------------------------------------------------------- #
+# Profiler mode: spans on the JAX profiler's clock, read back from the XSpace
+# --------------------------------------------------------------------------- #
+
+
+def _host_events(log_dir):
+    """``(line, name, start_ns, end_ns, stats)`` of every host-plane event
+    of the one XSpace under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    with warnings.catch_warnings():  # jaxlib's stats type has no __module__
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for i, line in enumerate(plane.lines):
+                    out.extend((i, e.name, e.start_ns, e.end_ns,
+                                dict(e.stats)) for e in line.events)
+    return out
+
+
+def _within(outer, inner):
+    return (inner[0] == outer[0] and outer[2] <= inner[2]
+            and inner[3] <= outer[3])
+
+
+def test_profiler_spans_land_in_the_xspace_not_in_memory(tmp_path):
+    import jax
+
+    trc = Tracer(profiler=True)
+    with jax.profiler.trace(str(tmp_path)):
+        with trc.span("outer", k=3) as s:
+            s.set_attrs(found=True)
+            with trc.span("inner"):
+                pass
+    assert trc.spans == []           # the XSpace is the store
+    evs = {e[1]: e for e in _host_events(str(tmp_path))}
+    outer, inner = evs["outer"], evs["inner"]
+    assert outer[4] == {"k": 3, "found": 1}
+    assert _within(outer, inner)
+
+
+@pytest.fixture(scope="module")
+def deployment():
+    """An elastic-lstm RTL deployment at test size, its batch-2 program
+    compiled."""
+    from repro.energy.hw import XC7S15
+    from repro.rtl.backend import RTLExecutable
+    from repro.verify.vectors import canonical_graph
+
+    dep = RTLExecutable(graph=canonical_graph("elastic-lstm")[0],
+                        artifacts={}, hw=XC7S15)
+    x = np.random.default_rng(0).normal(size=(2, 6, 1)).astype(np.float32)
+    want = np.asarray(dep(x))
+    return dep, x, want
+
+
+def test_deployment_call_is_one_span_tree_on_the_profiler_clock(
+        tmp_path, deployment):
+    import jax
+
+    dep, x, want = deployment
+    x3 = np.concatenate([x, x[:1]])
+    prev = set_tracer(Tracer(profiler=True))
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            got = np.asarray(dep(x))
+            dep(x3)                  # a new shape compiles in the window
+    finally:
+        set_tracer(prev)
+    np.testing.assert_array_equal(got, want)     # spans change no result
+    evs = sorted((e for e in _host_events(str(tmp_path))
+                  if e[1].startswith("rtl.")), key=lambda e: e[2])
+    calls = [e for e in evs if e[1] == "rtl.call"]
+    assert [c[4]["batch"] for c in calls] == [2, 3]
+    for call, cached in zip(calls, (1, 0)):
+        inside = [e for e in evs if e is not call and _within(call, e)]
+        assert [e[1] for e in inside] == [
+            "rtl.emulator.quantize", "rtl.emulator.dispatch",
+            "rtl.emulator.unpack"]
+        assert inside[1][4]["cached"] == cached
+        assert inside[1][4]["mode"] == "fused"
+
+
+def test_disabled_tracer_leaves_no_annotation(tmp_path, deployment):
+    import jax
+
+    dep, x, want = deployment
+    off = Tracer(enabled=False, profiler=True)
+    assert get_tracer().enabled is False
+    with jax.profiler.trace(str(tmp_path)):
+        with off.span("nope", k=1) as s:
+            s.set_attrs(more=2)
+            got = np.asarray(dep(x))
+    np.testing.assert_array_equal(got, want)
+    assert off.span("a") is off.span("b")        # the shared null span
+    names = {e[1] for e in _host_events(str(tmp_path))}
+    assert "nope" not in names
+    assert not {n for n in names if n.startswith("rtl.")}

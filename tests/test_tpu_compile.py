@@ -10,6 +10,9 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library.
 """
 import os
+import pathlib
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,12 @@ from repro.quant.fixedpoint import FxpFormat
 from repro.rtl import RTLEmulator
 from repro.rtl.oplib import mac_int_pallas
 from repro.verify.vectors import canonical_graph
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from bench.counts import KERNELS  # noqa: E402
 
 W8, A8, C16 = FxpFormat(8, 6), FxpFormat(8, 4), FxpFormat(16, 8)
 W12, A9 = FxpFormat(12, 9), FxpFormat(9, 4)
@@ -106,6 +115,31 @@ def test_emulator_walk_compiles(one_chip, monkeypatch, mode, arch, batch):
                           emu.params())
     text = emu.lower(_sds(one_chip, shape), params).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+#: an instruction of compiled HLO text: ``%name = type op(...)``
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? custom-call\(",
+                          re.M)
+
+
+@pytest.mark.parametrize("batch", [1, 4096])
+def test_fused_walk_kernels_keep_their_trace_names(one_chip, monkeypatch,
+                                                   batch):
+    """A TPU trace names a device operation by its HLO instruction. The
+    benchmark's kernel patterns (``bench/counts.KERNELS``), which the
+    roofline readers sum device time by, each match a custom call of the
+    compiled fused walk."""
+    monkeypatch.setattr(repro.kernels, "INTERPRET", False)
+    graph = canonical_graph("elastic-lstm")[0]
+    emu = RTLEmulator(graph, mode="fused")
+    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                          emu.params())
+    text = emu.lower(_sds(one_chip, (batch, 6, 1)),
+                     params).compile().as_text()
+    calls = _INSTRUCTION.findall(text)
+    assert calls, "no custom call in the compiled walk"
+    for kernel, pattern in KERNELS.items():
+        assert [c for c in calls if re.search(pattern, c)], (kernel, calls)
 
 
 def test_multi_design_walk_compiles(one_chip):

@@ -185,8 +185,9 @@ def test_workflow_run_once_emits_span_tree():
     # the Chrome export is valid JSON and preserves the tree
     import json as _json
     doc = _json.loads(_json.dumps(cap.trace.chrome()))
-    back = obs.from_chrome_trace(doc)
-    assert len(back) == len(spans)
+    assert len(doc["traceEvents"]) == len(spans)
+    assert sorted(ev["args"]["span_id"] for ev in doc["traceEvents"]) == \
+        sorted(s.span_id for s in spans)
 
     # non-degenerate latency distribution on the report
     m = rec.measurement
