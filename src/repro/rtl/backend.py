@@ -117,21 +117,19 @@ class RTLExecutable(Deployment):
     def __call__(self, x: jax.Array) -> jax.Array:
         trc = get_tracer()
         if not trc.enabled:                  # hoisted guard: skip the attrs
-            return self.emulator.run(x).outputs_f
+            return self.emulator.forward(x)
         with trc.span("rtl.call", batch=int(x.shape[0])):
-            return self.emulator.run(x).outputs_f
+            return self.emulator.forward(x)
 
     def run_many(self, xs) -> list:
         """Batched-throughput entry: see :meth:`RTLEmulator.run_many`."""
         return self.emulator.run_many(xs)
 
     def holds_program(self, shape, dtype) -> bool:
-        """Serving-router affinity probe: is a program for this float input
-        ``(shape, dtype)`` already compiled? Float inputs quantize to int32
-        before dispatch, so the emulator key is ``(shape, int32)``."""
-        import jax.numpy as jnp
-
-        return self.emulator.has_program(shape, jnp.int32)
+        """Serving-router affinity probe: is the program a call with this
+        float input ``(shape, dtype)`` runs — the emulator's float-in
+        program (:meth:`RTLEmulator.forward`) — already compiled?"""
+        return self.emulator.has_program(shape, dtype, float_io=True)
 
     @property
     def cycles(self) -> int:

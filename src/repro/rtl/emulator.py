@@ -21,8 +21,10 @@ overflowing keeps the f32 oracle exact.
 Execution model (DESIGN.md §7, §15): the emulator is a *staged executor*.
 ``__init__`` hoists every weight/bias/LUT conversion to a device constant
 once (``HWTemplate.prepare``); the graph walk is traced into a single
-``jax.jit``-compiled program per ``(iso_key, mode, input shape, dtype)``,
-held in a small :class:`~repro.rtl.program_cache.ProgramLRU` — so repeated
+``jax.jit``-compiled program per ``(iso_key, mode, input shape, dtype)``
+(and the deployment call's float-in program, :meth:`RTLEmulator.forward`,
+under its own tag), held in a small
+:class:`~repro.rtl.program_cache.ProgramLRU` — so repeated
 verification/measurement calls never retrace and never re-upload. The
 prepared *array* constants (weights, biases, ROM tables) are passed to the
 compiled program as traced arguments, not closed over, so designs with
@@ -204,34 +206,48 @@ class RTLEmulator:
             get_template(n.op).execute(n, env, em, mode)
         return env
 
-    def _cache_key(self, shape, dtype):
+    def _cache_key(self, shape, dtype, float_io: bool = False):
         # keyed on everything the traced program depends on besides the
         # array arguments: the design's isomorphism class, execution mode,
-        # pallas interpret flag, and the input aval
-        return (self.iso_key, self.mode, self.interpret,
-                tuple(int(d) for d in shape), jnp.dtype(dtype).name)
+        # pallas interpret flag, and the input aval; the float-in program
+        # of :meth:`forward` adds a tag, so it never aliases the int walk
+        key = (self.iso_key, self.mode, self.interpret,
+               tuple(int(d) for d in shape), jnp.dtype(dtype).name)
+        return key + ("float_io",) if float_io else key
 
-    def _program(self, shape, dtype):
-        """The compiled graph walk for one (shape, dtype), LRU-cached.
+    def _program(self, shape, dtype, float_io: bool = False):
+        """The compiled program for one (shape, dtype), LRU-cached.
 
         Returns ``(program, cache_hit)`` and keeps the cache observable:
         ``cache_hits``/``cache_misses``/``cache_evictions`` on the instance
         plus the matching ``rtl.emulator.cache_*`` process counters. The
-        program signature is ``prog(x_int, params)`` — array constants are
+        program signature is ``prog(x, params)`` — array constants are
         traced arguments, so any emulator whose graph shares this
         emulator's iso key can replay the program with its own params.
+        The graph walk takes int codes and returns every edge; with
+        ``float_io`` the program takes the float input and returns only
+        the dequantized output edge (:meth:`forward`).
         """
         mx = get_metrics()
 
         def build():
+            g = self.graph
+            in_fmt = g.edges[g.inputs[0]].fmt
+            out_fmt = g.edges[g.outputs[0]].fmt
+
             def walk(x_int, params):
                 self.trace_count += 1    # python side effect: trace-time
                 return self._execute(x_int, mode=self.mode, params=params)
 
-            return jax.jit(walk)
+            def forward(x, params):
+                x_int = fxp_to_int(x, in_fmt).astype(jnp.int32)
+                y = walk(x_int, params)[g.outputs[0]]
+                return y.astype(jnp.float32) / out_fmt.scale
+
+            return jax.jit(forward if float_io else walk)
 
         prog, hit, evicted = self._programs.get_or_build(
-            self._cache_key(shape, dtype), build)
+            self._cache_key(shape, dtype, float_io), build)
         if hit:
             self.cache_hits += 1
             mx.counter("rtl.emulator.cache_hit").inc()
@@ -243,22 +259,26 @@ class RTLEmulator:
                 mx.counter("rtl.emulator.cache_evict").inc(evicted)
         return prog, hit
 
-    def lower(self, x_int, params: Optional[Dict[str, Dict]] = None):
-        """Lower the compiled graph walk for input ``x_int`` — an array or
-        a ``jax.ShapeDtypeStruct`` — with this emulator's params (or the
-        given pytree of the same structure). ``.compile().as_text()`` of
-        the result is the program a dispatch of that shape runs."""
-        prog, _ = self._program(x_int.shape, x_int.dtype)
-        return prog.lower(x_int, self.params() if params is None else params)
+    def lower(self, x, params: Optional[Dict[str, Dict]] = None, *,
+              float_io: bool = False):
+        """Lower the compiled program for input ``x`` — an array or a
+        ``jax.ShapeDtypeStruct`` — with this emulator's params (or the
+        given pytree of the same structure): the graph walk, or with
+        ``float_io`` the float-in program of :meth:`forward`.
+        ``.compile().as_text()`` of the result is the program a dispatch
+        of that shape runs."""
+        prog, _ = self._program(x.shape, x.dtype, float_io)
+        return prog.lower(x, self.params() if params is None else params)
 
-    def has_program(self, shape, dtype) -> bool:
+    def has_program(self, shape, dtype, *, float_io: bool = False) -> bool:
         """Whether the LRU already holds a compiled program for this
-        input — the serving router's affinity probe
+        input (the graph walk, or with ``float_io`` the float-in program
+        of :meth:`forward`) — the serving router's affinity probe
         (:mod:`repro.serving.router`). Read-only: does not touch LRU
         order, so probing every pool member is side-effect free. Keys
         include the design's iso key, so with a shared ProgramLRU a
         replica counts as warm for any isomorphic sibling's program."""
-        return self._cache_key(shape, dtype) in self._programs
+        return self._cache_key(shape, dtype, float_io) in self._programs
 
     def cache_stats(self) -> Dict[str, int]:
         """Program-cache behavior + per-mode dispatch counts, one dict."""
@@ -347,6 +367,27 @@ class RTLEmulator:
         with get_tracer().span("rtl.emulator.quantize"):
             x_int = jnp.asarray(fxp_to_int(x, in_fmt), jnp.int32)
         return self.run_int(x_int)
+
+    def forward(self, x: jax.Array) -> jax.Array:
+        """Float input to dequantized output in ONE compiled program.
+
+        The deployment call (:class:`~repro.rtl.backend.RTLExecutable`):
+        quantization to the input format, the graph walk and the
+        dequantization of the output edge are traced into one program, so
+        a call launches one device program. Equals ``run(x).outputs_f``
+        element for element; returns no intermediate edge.
+        """
+        prog, hit = self._program(x.shape, x.dtype, float_io=True)
+        params = self.params()
+        self._count_dispatch(self.mode)
+        get_metrics().counter("rtl.emulator.dispatch.float_io").inc()
+        trc = get_tracer()
+        if trc.enabled:                      # hoisted guard: skip the attrs
+            with trc.span("rtl.emulator.dispatch", mode=self.mode,
+                          shape=str(tuple(x.shape)), cached=hit,
+                          design=self.graph.name, io="float"):
+                return prog(x, params)
+        return prog(x, params)
 
     # -- batched-throughput entry -------------------------------------------
     def run_many(self, xs: Union[jax.Array, Sequence[jax.Array]]

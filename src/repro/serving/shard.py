@@ -68,8 +68,7 @@ class ShardedExecutable:
 
     def holds_program(self, shape, dtype) -> bool:
         # programs are keyed on the padded int32 batch the dispatch actually
-        # runs, not the caller's float dtype (same contract as
-        # RTLExecutable.holds_program)
+        # runs, not the caller's float dtype
         b = self._padded_b(int(shape[0]))
         key = ((b,) + tuple(int(d) for d in shape[1:]),
                jnp.dtype(jnp.int32).name)

@@ -337,11 +337,10 @@ def test_deployment_call_is_one_span_tree_on_the_profiler_clock(
     assert [c[4]["batch"] for c in calls] == [2, 3]
     for call, cached in zip(calls, (1, 0)):
         inside = [e for e in evs if e is not call and _within(call, e)]
-        assert [e[1] for e in inside] == [
-            "rtl.emulator.quantize", "rtl.emulator.dispatch",
-            "rtl.emulator.unpack"]
-        assert inside[1][4]["cached"] == cached
-        assert inside[1][4]["mode"] == "fused"
+        assert [e[1] for e in inside] == ["rtl.emulator.dispatch"]
+        assert inside[0][4]["cached"] == cached
+        assert inside[0][4]["mode"] == "fused"
+        assert inside[0][4]["io"] == "float"
 
 
 def test_disabled_tracer_leaves_no_annotation(tmp_path, deployment):

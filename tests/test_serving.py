@@ -417,7 +417,8 @@ def test_executable_holds_program_probe(lstm_exe):
     assert not replica.holds_program(x.shape, x.dtype)
     replica(x)
     assert replica.holds_program(x.shape, x.dtype)
-    assert replica.emulator.has_program(x.shape, np.int32)
+    assert replica.emulator.has_program(x.shape, x.dtype, float_io=True)
+    assert not replica.emulator.has_program(x.shape, np.int32)
     assert not replica.holds_program((2, 6, 1), x.dtype)
 
 

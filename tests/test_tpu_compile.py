@@ -99,10 +99,24 @@ def test_mac_kernel_compiles(one_chip, mkn, bits):
     assert "tpu_custom_call" in text
 
 
+#: the two programs an emulator compiles per input shape: the graph walk on
+#: int32 codes, and the deployment call's float-in program (``forward``)
+IO = {"int": jnp.int32, "float": jnp.float32}
+
+
+def _lower(emu, one_chip, shape, io):
+    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                          emu.params())
+    return emu.lower(_sds(one_chip, shape, IO[io]), params,
+                     float_io=io == "float")
+
+
+@pytest.mark.parametrize("io", sorted(IO))
 @pytest.mark.parametrize("batch", [1, 4096])
 @pytest.mark.parametrize("arch", ["elastic-lstm", "elastic-conv1d"])
 @pytest.mark.parametrize("mode", ["fused", "pallas"])
-def test_emulator_walk_compiles(one_chip, monkeypatch, mode, arch, batch):
+def test_emulator_walk_compiles(one_chip, monkeypatch, mode, arch, batch,
+                                io):
     """The whole program of each design in each kernel mode — every node,
     as one dispatch runs it — compiles with its kernels compiled, not
     interpreted."""
@@ -111,9 +125,7 @@ def test_emulator_walk_compiles(one_chip, monkeypatch, mode, arch, batch):
     emu = RTLEmulator(graph, mode=mode)
     assert emu.interpret is False
     shape = (batch,) + tuple(graph.edges[graph.inputs[0]].shape)
-    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
-                          emu.params())
-    text = emu.lower(_sds(one_chip, shape), params).compile().as_text()
+    text = _lower(emu, one_chip, shape, io).compile().as_text()
     assert "tpu_custom_call" in text
 
 
@@ -122,20 +134,19 @@ _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*? custom-call\(",
                           re.M)
 
 
+@pytest.mark.parametrize("io", sorted(IO))
 @pytest.mark.parametrize("batch", [1, 4096])
 def test_fused_walk_kernels_keep_their_trace_names(one_chip, monkeypatch,
-                                                   batch):
+                                                   batch, io):
     """A TPU trace names a device operation by its HLO instruction. The
     benchmark's kernel patterns (``bench/counts.KERNELS``), which the
     roofline readers sum device time by, each match a custom call of the
-    compiled fused walk."""
+    compiled fused walk — and of the float-in program the deployment call
+    runs."""
     monkeypatch.setattr(repro.kernels, "INTERPRET", False)
     graph = canonical_graph("elastic-lstm")[0]
     emu = RTLEmulator(graph, mode="fused")
-    params = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
-                          emu.params())
-    text = emu.lower(_sds(one_chip, (batch, 6, 1)),
-                     params).compile().as_text()
+    text = _lower(emu, one_chip, (batch, 6, 1), io).compile().as_text()
     calls = _INSTRUCTION.findall(text)
     assert calls, "no custom call in the compiled walk"
     for kernel, pattern in KERNELS.items():
