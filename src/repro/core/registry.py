@@ -63,7 +63,9 @@ register(Component(
     notes="EP dispatch is collective-bound, no kernel template needed"))
 register(Component(
     "mamba2", ref="repro.model.ssm.mamba_apply",
-    template="repro.kernels.mamba2.ops"))
+    template="repro.kernels.mamba2.ops",
+    notes="mamba_apply runs ssd_chunked/ssd_step (any n_groups); the "
+          "Pallas SSD template takes n_groups=1 only and is off the path"))
 register(Component(
     "rwkv6", ref="repro.model.rwkv.rwkv_time_mix",
     template="repro.kernels.rwkv6.ops"))

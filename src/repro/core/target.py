@@ -62,7 +62,7 @@ def model_flops_estimate(cfg, shape) -> float:
         return 6.0 * n * shape.tokens
     if shape.kind == "prefill":
         return 2.0 * n * shape.tokens
-    return 2.0 * n * shape.global_batch          # decode: one token per seq
+    return 2.0 * n * shape.global_batch * shape.step_tokens   # decode
 
 
 # --------------------------------------------------------------------------- #
@@ -198,17 +198,38 @@ class Deployment:
 @dataclass
 class XLADeployment(Deployment):
     """The jitted-executable deployment: wall-clock timing on the device
-    JAX runs it on, with duty-1 power from the HWSpec."""
+    JAX runs it on, with duty-1 power from the HWSpec.
+
+    A call is the span ``xla.call`` (attrs ``kind``, ``arch``, ``batch``)
+    and counts ``xla.prefill.tokens`` (batch × sequence of the compiled
+    shape) or ``xla.decode.steps`` and ``xla.decode.tokens`` (``step_tokens``
+    per sequence of the batch)."""
 
     fn: Any                                     # compiled/jitted callable
     hw: HWSpec = TPU_V5E
     hlo_text: str = ""
     cost: Dict[str, float] = field(default_factory=dict)
+    kind: str = ""                              # "train" | "prefill" | "decode"
+    arch: str = ""
+    batch: int = 0
+    seq: int = 0
+    step_tokens: int = 1
 
     target = "xla"
 
     def __call__(self, *args):
-        return self.fn(*args)
+        mx = get_metrics()
+        if self.kind == "decode":
+            mx.counter("xla.decode.steps").inc()
+            mx.counter("xla.decode.tokens").inc(self.batch * self.step_tokens)
+        elif self.kind == "prefill":
+            mx.counter("xla.prefill.tokens").inc(self.batch * self.seq)
+        trc = get_tracer()
+        if not trc.enabled:                  # hoisted guard: skip the attrs
+            return self.fn(*args)
+        with trc.span("xla.call", kind=self.kind, arch=self.arch,
+                      batch=self.batch):
+            return self.fn(*args)
 
     def bind_step(self, fn) -> "XLADeployment":
         """Measure ``fn`` instead of the translated executable, keeping the
@@ -464,7 +485,11 @@ class XLATarget:
         dep = XLADeployment(fn=compiled, hw=hw, hlo_text=hlo,
                             cost={"flops": rep.flops_per_device,
                                   "bytes_accessed": rep.bytes_per_device,
-                                  "wire_bytes": rep.wire_bytes_per_device})
+                                  "wire_bytes": rep.wire_bytes_per_device},
+                            kind=kind, arch=st.cfg.name,
+                            batch=st.shape.global_batch,
+                            seq=st.shape.seq_len,
+                            step_tokens=st.shape.step_tokens)
         return syn, dep
 
 

@@ -145,7 +145,12 @@ class ModelConfig:
     frontend: Optional[str] = None          # "audio" | "vision" (stub embeddings)
     n_frontend_tokens: int = 0              # visual/audio tokens prepended/encoded
     frontend_dim: int = 0                   # raw embedding dim from the stub
-    shared_attn_every: int = 0              # zamba2: shared attn block cadence
+    # zamba2 hybrid: the shared attention block runs before the Mamba layer
+    # at each of these indices (call k uses block k % num_mem_blocks and
+    # its own rank-``adapter_rank`` MLP adapter)
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
     tie_embeddings: bool = False
     vocab_pad_multiple: int = 128
     dtype: str = "bfloat16"
@@ -158,6 +163,16 @@ class ModelConfig:
     # chunk the CE loss over positions (needed only when the vocab cannot be
     # sharded; the chunk-slice transpose pads cotangents back to full size)
     ce_chunked: bool = True
+
+    def __post_init__(self):
+        ids = tuple(self.hybrid_layer_ids)
+        object.__setattr__(self, "hybrid_layer_ids", ids)
+        if ids and (list(ids) != sorted(set(ids)) or ids[0] < 0
+                    or ids[-1] >= self.n_layers or self.num_mem_blocks < 1):
+            raise ValueError(
+                f"hybrid_layer_ids must be increasing layer indices below "
+                f"n_layers={self.n_layers} with num_mem_blocks >= 1, got "
+                f"{ids} and {self.num_mem_blocks}")
 
     # ---- derived ----------------------------------------------------------
     @property
@@ -189,14 +204,6 @@ class ModelConfig:
             k += ["moe"] * (self.n_layers - self.moe.first_dense)
             return tuple(k)
         return ("attn",) * self.n_layers
-
-    def shared_attn_points(self) -> Tuple[int, ...]:
-        """Layer indices AFTER which the zamba2 shared block is applied."""
-        if self.shared_attn_every <= 0:
-            return ()
-        return tuple(
-            i for i in range(self.n_layers) if (i + 1) % self.shared_attn_every == 0
-        )
 
     def with_(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -239,6 +246,9 @@ class ShapeConfig:
     kind: str                      # "train" | "prefill" | "decode"
     seq_len: int
     global_batch: int
+    #: tokens per sequence one decode step takes; more than 1 continues a
+    #: prefill through the cache (the hybrid and attention paths)
+    step_tokens: int = 1
 
     @property
     def tokens(self) -> int:

@@ -15,7 +15,7 @@ from repro.kernels.mamba2.kernel import ssd_pallas
 def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         Cm: jax.Array, h0: Optional[jax.Array] = None, *, chunk: int = 128
         ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B,S,H,P); dt: (B,S,H); A: (H,); B/C: (B,S,G,N). n_groups G=1.
+    """x: (B,S,H,P); dt: (B,S,H); A: (H,); B/C: (B,S,G,N) with G=1.
 
     Returns (y (B,S,H,P), final_state (B,H,P,N)). Like the WKV6 template,
     a nonzero initial state is folded in post-hoc (the recurrence is linear
@@ -23,7 +23,11 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     """
     B, S, H, P = x.shape
     G = Bm.shape[2]
-    assert G == 1, "template instantiated for n_groups=1 (zamba2)"
+    if G != 1:
+        raise NotImplementedError(
+            f"the Pallas SSD template reads one B/C group, got G={G}; "
+            "repro.model.ssm.ssd_chunked handles grouped B/C (the path "
+            "mamba_apply takes)")
     xk = x.transpose(0, 2, 1, 3)                      # (B,H,S,P)
     y, hf = ssd_pallas(xk, dt.astype(jnp.float32), A.astype(jnp.float32),
                        Bm[:, :, 0], Cm[:, :, 0], chunk=chunk,

@@ -103,34 +103,40 @@ def _compile_cell(cfg, shape, mcfg, mesh, par):
 
 
 def extrapolation_plan(cfg):
-    """[(n_layers, weight)] s.t. cost(full) = Σ w_i · cost(L_i).
+    """[(cfg_L, weight)] s.t. cost(cfg) = Σ w_i · cost(cfg_L_i).
 
     Per-layer HLO is identical within a homogeneous group, so cost is exactly
-    affine in the group's layer count; two (three for the zamba2 unit
-    structure) reduced-depth *unrolled* compiles recover the exact
-    coefficients. Validated against full unrolled compiles in
+    affine in the group's layer count; two reduced-depth *unrolled* compiles
+    recover the exact coefficients (three for zamba2, whose shared-block
+    calls are a second count). Validated against full unrolled compiles in
     EXPERIMENTS.md §Dry-run.
     """
     T = cfg.n_layers
     if cfg.family in ("lstm", "conv1d"):
-        return [(T, 1.0)]
-    if cfg.family == "hybrid" and cfg.shared_attn_every:
-        # zamba2 unit structure: f(T) = a + n_units·c_unit + rem·b_layer.
-        # Wide spacing (Δ=2 units / 2 layers) damps per-compile noise.
-        u = cfg.shared_attn_every
-        n_units = T // u
-        rem = T - n_units * u
-        # c_unit=(f(3u)-f(u))/2, b=(f(u+2)-f(u))/2, a=f(u)-c_unit
-        w_u = 1.0 - (n_units - 1) / 2.0 - rem / 2.0
-        return [(u, w_u), (3 * u, (n_units - 1) / 2.0), (u + 2, rem / 2.0)]
+        return [(cfg, 1.0)]
+    ids = cfg.hybrid_layer_ids
+    if cfg.family == "hybrid" and len(ids) >= 2:
+        # zamba2: f = a + n_calls·c_call + n_layers·b_layer (every layer has
+        # a Mamba block; calls alternate blocks of one shape). Fit on
+        # (ids[0]+1 layers, 1 call), (ids[0]+3, 1), (ids[1]+1, 2 calls).
+        n = len(ids)
+        la, lb, lc = ids[0] + 1, ids[0] + 3, ids[1] + 1
+        w_c = n - 1.0
+        w_b = (T - lc * w_c - la * (2.0 - n)) / (lb - la)
+        w_a = 2.0 - n - w_b
+        one = ids[:1]
+        return [(cfg.with_(n_layers=la, hybrid_layer_ids=one), w_a),
+                (cfg.with_(n_layers=lb, hybrid_layer_ids=one), w_b),
+                (cfg.with_(n_layers=lc, hybrid_layer_ids=ids[:2]), w_c)]
     k = cfg.moe.first_dense if (cfg.family == "moe" and cfg.moe) else 0
     L1 = k + 1
     delta = min(6, T - L1)
     L2 = L1 + delta
-    if T <= L2 or delta <= 0:
-        return [(T, 1.0)]
+    if T <= L2 or delta <= 0 or cfg.family == "hybrid":
+        return [(cfg, 1.0)]
     w2 = (T - L1) / delta
-    return [(L1, 1.0 - w2), (L2, w2)]
+    return [(cfg.with_(n_layers=L1), 1.0 - w2),
+            (cfg.with_(n_layers=L2), w2)]
 
 
 def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
@@ -184,8 +190,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         wire = 0.0
         coll_counts: dict = {}
         dts = [dt_scan]
-        for L, w in extrapolation_plan(cfg):
-            cfg_L = cfg.with_(n_layers=L)
+        for cfg_L, w in extrapolation_plan(cfg):
             cost_L, _, hlo_L, dt_L = _compile_cell(cfg_L, shape, mcfg, mesh,
                                                    par)
             st_L = parse_collectives(hlo_L, mesh.size)

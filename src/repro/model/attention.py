@@ -61,11 +61,13 @@ def attention_core(
     v: jax.Array,           # (B, Sk, H, hd)
     ctx: Ctx,
     causal: bool,
-    q_offset: jax.Array | int = 0,   # absolute position of q[.., 0]
+    q_offset: jax.Array | int = 0,   # position of q[.., 0]: one, or (B,)
     kv_len: Optional[jax.Array] = None,  # valid cache length (decode)
+    scale: Optional[float] = None,       # default: head_dim ** -0.5
 ) -> jax.Array:
     """Softmax attention; dispatches ref-einsum / chunked / Pallas template."""
-    if ctx.attn_impl == "flash" and causal and q.shape[1] == k.shape[1]:
+    if (ctx.attn_impl == "flash" and causal and q.shape[1] == k.shape[1]
+            and scale is None):
         from repro.kernels.flash_attention import ops as flash_ops
 
         return flash_ops.flash_attention(q, k, v, causal=True)
@@ -78,7 +80,8 @@ def attention_core(
             axis=2, keepdims=True)).astype(v.dtype)
     # auto-dispatch: un-repeated K/V (fewer kv heads) -> grouped GQA path
     block = _attn_block_grouped if k.shape[2] != q.shape[2] else _attn_block
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     sq, sk = q.shape[1], k.shape[1]
     if sq <= FULL_ATTN_MAX_SEQ or sq != sk:
         return block(q, k, v, scale, causal, q_offset, kv_len)
@@ -98,6 +101,14 @@ def attention_core(
     return out[:, :sq]
 
 
+def _causal_mask(q_offset, sq: int, sk: int) -> jax.Array:
+    """(B|1, Sq, Sk): key position <= query position, where ``q_offset`` is
+    the absolute position of the first query, one for all rows or (B,)."""
+    qpos = (jnp.reshape(jnp.asarray(q_offset), (-1, 1, 1))
+            + jnp.arange(sq)[None, :, None])
+    return jnp.arange(sk)[None, None, :] <= qpos
+
+
 def _attn_block(q, k, v, scale, causal, q_offset, kv_len):
     sq, sk = q.shape[1], k.shape[1]
     logits = jnp.einsum(
@@ -105,16 +116,12 @@ def _attn_block(q, k, v, scale, causal, q_offset, kv_len):
     ) * scale
     mask = None
     if causal:
-        qpos = q_offset + jnp.arange(sq)[:, None]
-        kpos = jnp.arange(sk)[None, :]
-        mask = kpos <= qpos
+        mask = _causal_mask(q_offset, sq, sk)[:, None]   # (B|1,1,Sq,Sk)
     if kv_len is not None:
         valid = jnp.arange(sk)[None, :] < jnp.reshape(kv_len, (-1, 1))
         valid = valid[:, None, None, :]  # (B,1,1,Sk)
-        mask = valid if mask is None else (mask[None, None] & valid)
+        mask = valid if mask is None else (mask & valid)
     if mask is not None:
-        if mask.ndim == 2:
-            mask = mask[None, None]
         logits = jnp.where(mask, logits, jnp.float32(-1e30))
     w = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", w.astype(v.dtype), v)
@@ -132,9 +139,8 @@ def _attn_block_grouped(q, k, v, scale, causal, q_offset, kv_len):
                         preferred_element_type=jnp.float32) * scale
     mask = None
     if causal:
-        qpos = q_offset + jnp.arange(sq)[:, None]
-        kpos = jnp.arange(sk if (sk := k.shape[1]) else 0)[None, :]
-        mask = (kpos <= qpos)[None, None, None]        # (1,1,1,Sq,Sk)
+        mask = _causal_mask(q_offset, sq, k.shape[1])[:, None, None]
+        #                                              (B|1,1,1,Sq,Sk)
     if kv_len is not None:
         valid = jnp.arange(k.shape[1])[None, :] < jnp.reshape(kv_len, (-1, 1))
         valid = valid[:, None, None, None, :]          # (B,1,1,1,Sk)
@@ -154,12 +160,14 @@ def attn_apply(
     cross_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
     causal: bool = True,     # False: encoder self-attention
     use_rope: bool = True,
+    scale: Optional[float] = None,   # score scale; default head_dim ** -0.5
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """Self- or cross-attention. Returns (out, updated_cache).
 
     Cache layout: {"k": (B, S_max, KV, hd), "v": ..., "pos": (B,) int32}.
     Head counts are derived from the param shapes so the zamba2 shared block
-    (2·d_model input) and whisper cross-attention reuse this code path.
+    (2·d_model input, d_model output, score scale (head_dim/2)^-½) and
+    whisper cross-attention reuse this code path.
     """
     cfg = ctx.cfg
     dt = ctx.compute_dtype
@@ -196,8 +204,11 @@ def attn_apply(
             assert cache is not None, "decode requires a KV cache"
             # scatter the new K/V at position `pos`, then attend over the
             # cache (in-place dynamic-update-slice: O(1) extra traffic with
-            # buffer donation, matching a production decode engine)
+            # buffer donation, matching a production decode engine). A step
+            # of S > 1 tokens per sequence continues a prefill through the
+            # cache: its queries attend causally from each row's `pos`.
             pos = cache["pos"]  # (B,) current lengths
+            S = h.shape[1]
 
             def upd(buf, new):
                 f = lambda b1, n1, p1: jax.lax.dynamic_update_slice(
@@ -207,11 +218,13 @@ def attn_apply(
 
             k_cache = upd(cache["k"].astype(dt), k)
             v_cache = upd(cache["v"].astype(dt), v)
-            new_cache = {"k": k_cache, "v": v_cache, "pos": pos + 1}
+            new_cache = {"k": k_cache, "v": v_cache, "pos": pos + S}
             k, v = k_cache, v_cache
-            kv_len = pos + 1
-            causal = False  # masking handled via kv_len
-            q_offset = 0
+            if S == 1:
+                kv_len = pos + 1
+                causal = False  # masking handled via kv_len
+            else:
+                q_offset = pos
         else:  # prefill: return the populated cache
             new_cache = {
                 "k": k,
@@ -222,7 +235,8 @@ def attn_apply(
     if not ctx.par.gqa_grouped:        # baseline: materialized repeat
         k = _repeat_kv(k, H // KV)
         v = _repeat_kv(v, H // KV)
-    o = attention_core(q, k, v, ctx, causal=causal, q_offset=q_offset, kv_len=kv_len)
+    o = attention_core(q, k, v, ctx, causal=causal, q_offset=q_offset,
+                       kv_len=kv_len, scale=scale)
     o = o.reshape(h.shape[0], h.shape[1], H * hd)
     out = (o @ p["wo"].astype(dt)).astype(h.dtype)
     return out, new_cache

@@ -234,7 +234,7 @@ def input_specs(cfg: ModelConfig,
                                           jnp.float32),
                 "y": jax.ShapeDtypeStruct((B, c.out_features), jnp.float32)}
     sds: Dict[str, jax.ShapeDtypeStruct] = {}
-    tok_s = 1 if shape.kind == "decode" else S
+    tok_s = shape.step_tokens if shape.kind == "decode" else S
     sds["tokens"] = jax.ShapeDtypeStruct((B, tok_s), jnp.int32)
     if shape.kind == "train":
         sds["targets"] = jax.ShapeDtypeStruct((B, S), jnp.int32)
@@ -272,18 +272,22 @@ class Stepper:
     # --- abstract (dry-run) -------------------------------------------------
     def abstract_inputs(self):
         sds = input_specs(self.cfg, self.shape)
+        params = abstract_params(self.schema,
+                                 dtype_override=jnp.dtype(self.par.param_dtype))
         if self.shape.kind == "train":
-            params = abstract_params(self.schema)
             opt = tree_map_pspec(
                 lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype),
                 opt_state_schema(self.schema, self.mesh_cfg))
             return {"params": params, "opt_state": opt, "batch": sds}
-        params = abstract_params(self.schema)
         out = {"params": params, "batch": sds}
         if self.shape.kind == "decode":
-            cache_schema = self.cache_schema()
+            # activation entries (bf16 in the schema: KV, conv windows) are
+            # kept at the compute dtype, as prefill and decode write them
+            cdt = jnp.dtype(self.par.compute_dtype)
             out["cache"] = tree_map_pspec(
-                lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype), cache_schema)
+                lambda s: jax.ShapeDtypeStruct(
+                    s.shape, cdt if s.dtype == jnp.bfloat16 else s.dtype),
+                self.cache_schema())
         return out
 
     def cache_schema(self):

@@ -9,8 +9,11 @@ template is validated against.
 
 Layout notes (TP over the "model" axis):
 - z/x/dt projections are column-sharded over d_inner / heads,
-- B/C projections are per-group (n_groups=1 here) and replicated,
+- B/C projections are per-group (``n_groups`` groups of N; head h reads
+  group h // (H / n_groups)) and replicated,
 - out_proj is row-sharded; XLA inserts the single block all-reduce.
+The depthwise conv over [x, B, C] has a bias; the gated RMSNorm is taken
+per group (d_inner / n_groups wide).
 State cache (decode): {"ssm": (B,H,P,N) f32, "conv_x/B/C": rolling windows}.
 """
 from __future__ import annotations
@@ -53,6 +56,9 @@ def mamba_schema(cfg: ModelConfig, tp: int = 16):
         "conv_x": PSpec((w, d_inner), P(None, ia), scale=0.5),
         "conv_B": PSpec((w, gN), P(None, None), scale=0.5),
         "conv_C": PSpec((w, gN), P(None, None), scale=0.5),
+        "conv_x_bias": PSpec((d_inner,), P(ia), init="zeros"),
+        "conv_B_bias": PSpec((gN,), P(None), init="zeros"),
+        "conv_C_bias": PSpec((gN,), P(None), init="zeros"),
         "A_log": PSpec((H,), P(ha), init="zeros"),       # A = -exp(A_log) = -1
         "dt_bias": PSpec((H,), P(ha), init="zeros"),
         "D": PSpec((H,), P(ha), init="ones"),
@@ -87,21 +93,26 @@ def mamba_state_schema(cfg: ModelConfig, batch: int, dp_axes, tp: int = 16):
 # ---------------------------------------------------------------------------
 
 
-def _causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """x: (B, S, C), w: (W, C) depthwise. Causal: y_t = sum_k w[k] x_{t-W+1+k}."""
+def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
+                 prev: Optional[jax.Array] = None):
+    """x: (B, S, C), w: (W, C) depthwise, b: (C,); ``prev`` (B, W-1, C) the
+    inputs before x (zeros when None). Causal: y_t = silu(b + sum_k w[k]
+    x_{t-W+1+k}). Returns (y, the last W-1 inputs: the next call's prev)."""
     W = w.shape[0]
-    pad = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
-    y = jnp.zeros_like(x)
+    if prev is None:
+        prev = jnp.zeros((x.shape[0], W - 1, x.shape[2]), x.dtype)
+    pad = jnp.concatenate([prev.astype(x.dtype), x], axis=1)
+    y = jnp.broadcast_to(b.astype(x.dtype), x.shape)
     for k in range(W):
         y = y + pad[:, k : k + x.shape[1], :] * w[k][None, None, :]
-    return jax.nn.silu(y)
+    return jax.nn.silu(y), pad[:, x.shape[1]:, :]
 
 
-def _conv_step(x_t: jax.Array, prev: jax.Array, w: jax.Array):
+def _conv_step(x_t: jax.Array, prev: jax.Array, w: jax.Array, b: jax.Array):
     """x_t: (B, C); prev: (B, W-1, C) rolling window. Returns (y_t, new_prev)."""
     window = jnp.concatenate([prev, x_t[:, None, :]], axis=1)  # (B, W, C)
     y = jnp.einsum("bwc,wc->bc", window.astype(jnp.float32),
-                   w.astype(jnp.float32))
+                   w.astype(jnp.float32)) + b.astype(jnp.float32)
     return jax.nn.silu(y).astype(x_t.dtype), window[:, 1:, :]
 
 
@@ -230,10 +241,14 @@ def ssd_step(
 
 
 def _gated_rmsnorm(y: jax.Array, z: jax.Array, scale: jax.Array,
-                   eps: float = 1e-5) -> jax.Array:
-    yf = (y * jax.nn.silu(z)).astype(jnp.float32)
-    ms = jnp.mean(jnp.square(yf), axis=-1, keepdims=True)
-    return (yf * jax.lax.rsqrt(ms + eps) * scale.astype(jnp.float32)).astype(y.dtype)
+                   groups: int = 1, eps: float = 1e-5) -> jax.Array:
+    """RMSNorm of y·silu(z), taken over each of ``groups`` equal slices of
+    the last axis."""
+    yf = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    yg = yf.reshape(*yf.shape[:-1], groups, yf.shape[-1] // groups)
+    ms = jnp.mean(jnp.square(yg), axis=-1, keepdims=True)
+    yn = (yg * jax.lax.rsqrt(ms + eps)).reshape(yf.shape)
+    return (yn * scale.astype(jnp.float32)).astype(y.dtype)
 
 
 def mamba_apply(
@@ -259,49 +274,51 @@ def mamba_apply(
                            + p["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
 
-    new_state = None
-    if ctx.mode == "decode":
-        assert state is not None and S == 1
-        xs, cx = _conv_step(x[:, 0], state["conv_x"].astype(dt_), p["conv_x"])
-        Bs, cB = _conv_step(Bm[:, 0], state["conv_B"].astype(dt_), p["conv_B"])
-        Cs, cC = _conv_step(Cm[:, 0], state["conv_C"].astype(dt_), p["conv_C"])
-        y, h_new = ssd_step(
-            xs.reshape(B, H, Pd), dt_f[:, 0], A,
-            Bs.reshape(B, s.n_groups, N), Cs.reshape(B, s.n_groups, N),
-            state["ssm"],
-        )
-        y = y + p["D"].astype(jnp.float32)[None, :, None] * xs.reshape(B, H, Pd)
-        y = y.reshape(B, 1, d_inner).astype(dt_)
-        new_state = {"ssm": h_new, "conv_x": cx.astype(x.dtype),
-                     "conv_B": cB.astype(x.dtype),
-                     "conv_C": cC.astype(x.dtype)}
-    else:
-        xc = _causal_conv(x, p["conv_x"].astype(dt_))
-        Bc = _causal_conv(Bm, p["conv_B"].astype(dt_))
-        Cc = _causal_conv(Cm, p["conv_C"].astype(dt_))
-        h0 = state["ssm"] if state is not None else None
-        y4, h_final = ssd_chunked(
-            xc.reshape(B, S, H, Pd), dt_f, A,
-            Bc.reshape(B, S, s.n_groups, N), Cc.reshape(B, S, s.n_groups, N),
-            chunk=min(s.chunk, S), h0=h0,
-        )
-        y4 = y4 + (p["D"].astype(jnp.float32)[None, None, :, None]
-                   * xc.reshape(B, S, H, Pd).astype(jnp.float32)).astype(y4.dtype)
-        y = y4.reshape(B, S, d_inner).astype(dt_)
-        if ctx.mode == "prefill":
-            W = s.conv_width
-            padx = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))[:, -(W - 1):, :] \
-                if S < W - 1 else x[:, -(W - 1):, :]
-            padB = Bm[:, -(W - 1):, :] if S >= W - 1 else \
-                jnp.pad(Bm, ((0, 0), (W - 1 - S, 0), (0, 0)))
-            padC = Cm[:, -(W - 1):, :] if S >= W - 1 else \
-                jnp.pad(Cm, ((0, 0), (W - 1 - S, 0), (0, 0)))
-            new_state = {"ssm": h_final,
-                         "conv_x": padx.astype(x.dtype),
-                         "conv_B": padB.astype(x.dtype),
-                         "conv_C": padC.astype(x.dtype)}
+    # the state-space part (conv + SSD), named for device-time attribution
+    with jax.named_scope("mamba2.ssd"):
+        new_state = None
+        if ctx.mode == "decode" and S == 1:
+            assert state is not None
+            xs, cx = _conv_step(x[:, 0], state["conv_x"].astype(dt_), p["conv_x"],
+                                p["conv_x_bias"])
+            Bs, cB = _conv_step(Bm[:, 0], state["conv_B"].astype(dt_), p["conv_B"],
+                                p["conv_B_bias"])
+            Cs, cC = _conv_step(Cm[:, 0], state["conv_C"].astype(dt_), p["conv_C"],
+                                p["conv_C_bias"])
+            y, h_new = ssd_step(
+                xs.reshape(B, H, Pd), dt_f[:, 0], A,
+                Bs.reshape(B, s.n_groups, N), Cs.reshape(B, s.n_groups, N),
+                state["ssm"],
+            )
+            y = y + p["D"].astype(jnp.float32)[None, :, None] * xs.reshape(B, H, Pd)
+            y = y.reshape(B, 1, d_inner).astype(dt_)
+            new_state = {"ssm": h_new, "conv_x": cx.astype(x.dtype),
+                         "conv_B": cB.astype(x.dtype),
+                         "conv_C": cC.astype(x.dtype)}
+        else:
+            # train / prefill from zeros; a decode step of S > 1 tokens
+            # continues from the cached conv windows and SSM state
+            prev = state if ctx.mode == "decode" else None
+            conv = {n: _causal_conv(t, p[f"conv_{n}"].astype(dt_),
+                                    p[f"conv_{n}_bias"],
+                                    prev[f"conv_{n}"] if prev else None)
+                    for n, t in (("x", x), ("B", Bm), ("C", Cm))}
+            xc, Bc, Cc = (conv[n][0] for n in ("x", "B", "C"))
+            h0 = state["ssm"] if state is not None else None
+            y4, h_final = ssd_chunked(
+                xc.reshape(B, S, H, Pd), dt_f, A,
+                Bc.reshape(B, S, s.n_groups, N), Cc.reshape(B, S, s.n_groups, N),
+                chunk=min(s.chunk, S), h0=h0,
+            )
+            y4 = y4 + (p["D"].astype(jnp.float32)[None, None, :, None]
+                       * xc.reshape(B, S, H, Pd).astype(jnp.float32)).astype(y4.dtype)
+            y = y4.reshape(B, S, d_inner).astype(dt_)
+            if ctx.mode in ("prefill", "decode"):
+                new_state = {"ssm": h_final,
+                             **{f"conv_{n}": conv[n][1].astype(x.dtype)
+                                for n in ("x", "B", "C")}}
 
-    yn = _gated_rmsnorm(y, z, p["norm_scale"])
+    yn = _gated_rmsnorm(y, z, p["norm_scale"], groups=s.n_groups)
     out = (yn @ p["w_out"].astype(dt_)).astype(hx.dtype)
     return out, new_state
 

@@ -9,9 +9,11 @@ default) or via ``lax.scan`` (fast compile; ``ParallelismConfig.scan_layers``).
 Block kinds:
   attn      — pre-norm attention + MLP (dense archs; d_ff per group)
   moe       — pre-norm attention + MoE FFN (incl. shared experts)
-  mamba2    — pre-norm Mamba2 (zamba2 hybrid); zamba2 additionally applies a
-              *shared* full attention block every ``shared_attn_every`` layers
-              on concat(h, h_emb0) (weights shared across invocations)
+  mamba2    — pre-norm Mamba2 (zamba2 hybrid); before the Mamba layer at each
+              of ``hybrid_layer_ids`` zamba2 runs one of ``num_mem_blocks``
+              *shared* attention+MLP blocks (alternating) on
+              concat(h, h_emb0), with a per-call MLP adapter, and adds its
+              output through a per-layer linear to that Mamba layer's input
   rwkv6     — RWKV6 time-mix + channel-mix
   enc/dec   — whisper encoder (non-causal) and decoder (causal + cross-attn)
 """
@@ -32,7 +34,8 @@ from repro.model import ssm as ssm_mod
 from repro.model.attention import attn_apply, attn_schema, cache_schema
 from repro.model.layers import (Ctx, PSpec, apply_mlp, apply_norm,
                                 embed_schema, embed_tokens, lm_logits,
-                                mlp_schema, norm_schema, tree_map_pspec)
+                                mlp_schema, norm_schema, shard_axis,
+                                tree_map_pspec)
 
 # ---------------------------------------------------------------------------
 # Group structure
@@ -103,23 +106,29 @@ def block_schema(cfg: ModelConfig, kind: str, tp: int):
 
 
 def shared_block_schema(cfg: ModelConfig, tp: int):
-    """zamba2 shared attention block on concat(h, emb0) — width 2·d_model."""
-    d2 = 2 * cfg.d_model
-    ff_tp = cfg.d_ff % tp == 0 and tp > 1
-    return {
-        "norm1": norm_schema(cfg, d=d2),
-        "attn": attn_schema(cfg, tp, d_in=d2, d_out=d2),
-        "norm2": norm_schema(cfg, d=d2),
-        "mlp": {
-            "w_gate": PSpec((d2, cfg.d_ff),
-                            P(None, "model" if ff_tp else None)),
-            "w_up": PSpec((d2, cfg.d_ff),
-                          P(None, "model" if ff_tp else None)),
-            "wo": PSpec((cfg.d_ff, d2),
-                        P("model" if ff_tp else None, None)),
-        },
-        "out_proj": PSpec((d2, cfg.d_model), P()),
+    """zamba2: ``num_mem_blocks`` shared blocks (attention on concat(h, emb0),
+    width 2·d_model, to d_model; then a GeGLU MLP at d_model), and for each
+    hybrid call k its rank-``adapter_rank`` adapter on the MLP's gate/up
+    projection and its linear into the Mamba layer's input. Blocks, adapters
+    and linears are tuples, not stacks: every call picks its own by a static
+    index, so no weight is sliced out of a stack (a copy per step)."""
+    d, f, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    fa = shard_axis(f, tp)
+    block = {
+        "norm_in": norm_schema(cfg, d=2 * d),
+        "attn": attn_schema(cfg, tp, d_in=2 * d, d_out=d),
+        "norm_ff": norm_schema(cfg),
+        "mlp": {"w_gate": PSpec((d, f), P(None, fa)),
+                "w_up": PSpec((d, f), P(None, fa)),
+                "wo": PSpec((f, d), P(fa, None))},
     }
+    adapter = {"a": PSpec((d, r), P()),
+               "b_gate": PSpec((r, f), P(None, fa)),
+               "b_up": PSpec((r, f), P(None, fa))}
+    n_calls = len(cfg.hybrid_layer_ids)
+    return {"blocks": (block,) * cfg.num_mem_blocks,
+            "adapters": (adapter,) * n_calls,
+            "linear": (PSpec((d, d), P()),) * n_calls}
 
 
 def _stack(n: int, tree):
@@ -144,7 +153,7 @@ def param_schema(cfg: ModelConfig, tp: int = 16):
     sch: Dict[str, Any] = {"embed": embed_schema(cfg, tp)}
     for gi, (kind, count) in enumerate(group_structure(cfg)):
         sch[f"g{gi}"] = _stack(count, block_schema(cfg, kind, tp))
-    if cfg.family == "hybrid" and cfg.shared_attn_every:
+    if cfg.family == "hybrid" and cfg.hybrid_layer_ids:
         sch["shared"] = shared_block_schema(cfg, tp)
     if cfg.family == "ssm":
         sch["ln0"] = norm_schema(cfg)
@@ -198,10 +207,11 @@ def model_cache_schema(cfg: ModelConfig, batch: int, seq: int, mesh_cfg,
                                 P(bspec, None, kva, None), dtype=jnp.bfloat16)
                 layers.append(c)
     out: Dict[str, Any] = {"layers": tuple(layers)}
-    if cfg.family == "hybrid" and cfg.shared_attn_every:
+    if cfg.family == "hybrid" and cfg.hybrid_layer_ids:
+        # one KV cache per call: each call attends over a different input
         out["shared"] = tuple(
             cache_schema(cfg, batch, seq, tp, dp)
-            for _ in cfg.shared_attn_points()
+            for _ in cfg.hybrid_layer_ids
         )
     return out
 
@@ -235,9 +245,9 @@ def _stacked_cache_schema(cfg, batch, seq, mesh_cfg, tp, seq_shard=False):
         entry = _group_cache_entry(cfg, kind, batch, seq, mesh_cfg, tp,
                                    seq_shard)
         out[f"g{gi}"] = None if entry is None else _stack(count, entry)
-    if cfg.family == "hybrid" and cfg.shared_attn_every:
+    if cfg.family == "hybrid" and cfg.hybrid_layer_ids:
         dp = mesh_cfg.dp_axes
-        out["shared"] = _stack(len(cfg.shared_attn_points()),
+        out["shared"] = _stack(len(cfg.hybrid_layer_ids),
                                cache_schema(cfg, batch, seq, tp, dp))
     return out
 
@@ -264,9 +274,12 @@ def _apply_moe_block(p, x, ctx: Ctx, cache):
     return ctx.constrain(x + m), new_cache, aux
 
 
-def _apply_mamba_block(p, x, ctx: Ctx, cache):
+def _apply_mamba_block(p, x, ctx: Ctx, cache, mix=None):
+    """Pre-norm Mamba2, residual from ``x``. At a zamba2 hybrid layer
+    ``mix`` (the shared block's output) is added to the Mamba input only."""
+    u = x if mix is None else x + mix
     m, new_cache = ssm_mod.mamba_apply(p["mamba"],
-                                       apply_norm(p["norm1"], x, ctx.cfg), ctx,
+                                       apply_norm(p["norm1"], u, ctx.cfg), ctx,
                                        state=cache)
     return ctx.constrain(x + m), new_cache, jnp.float32(0.0)
 
@@ -307,19 +320,26 @@ def _apply_dec_block(p, x, ctx: Ctx, cache, enc_kv):
     return ctx.constrain(x + m), new_cache, jnp.float32(0.0)
 
 
-def _apply_shared_block(p, x, emb0, ctx: Ctx, cache):
-    """zamba2 shared attention block; input concat(h, emb0), width 2d."""
-    u = jnp.concatenate([x, emb0], axis=-1)
-    a, new_cache = attn_apply(p["attn"], apply_norm(p["norm1"], u, ctx.cfg),
-                              ctx, cache=cache)
-    u = u + a
+def _apply_shared_block(sp, k: int, x, emb0, ctx: Ctx, cache):
+    """zamba2 hybrid call ``k``: block ``k % num_mem_blocks`` on
+    concat(x, emb0), no residual inside; returns (its output through the
+    call's linear — the term added to the Mamba layer's input —, the call's
+    KV cache)."""
+    cfg = ctx.cfg
     dt = ctx.compute_dtype
-    un = apply_norm(p["norm2"], u, ctx.cfg).astype(dt)
-    mp = p["mlp"]
-    h = jax.nn.silu(un @ mp["w_gate"].astype(dt)) * (un @ mp["w_up"].astype(dt))
-    u = u + (h @ mp["wo"].astype(dt)).astype(u.dtype)
-    out = (u.astype(dt) @ p["out_proj"].astype(dt)).astype(x.dtype)
-    return ctx.constrain(x + out), new_cache
+    with jax.named_scope("zamba2.shared_block"):
+        bp, ad = sp["blocks"][k % cfg.num_mem_blocks], sp["adapters"][k]
+        u = apply_norm(bp["norm_in"], jnp.concatenate([x, emb0], axis=-1), cfg)
+        a, new_cache = attn_apply(bp["attn"], u, ctx, cache=cache,
+                                  scale=(cfg.hd / 2) ** -0.5)
+        g = apply_norm(bp["norm_ff"], a, cfg).astype(dt)
+        mp = bp["mlp"]
+        low = g @ ad["a"].astype(dt)
+        gate = g @ mp["w_gate"].astype(dt) + low @ ad["b_gate"].astype(dt)
+        up = g @ mp["w_up"].astype(dt) + low @ ad["b_up"].astype(dt)
+        t = (jax.nn.gelu(gate, approximate=False) * up) @ mp["wo"].astype(dt)
+        out = (t @ sp["linear"][k].astype(dt)).astype(x.dtype)
+    return ctx.constrain(out), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -352,7 +372,8 @@ def apply_model(
     if ctx.positions is None:
         if ctx.mode == "decode":
             pos0 = _decode_positions(cfg, cache, ctx, B)
-            ctx = dataclasses.replace(ctx, positions=jnp.reshape(pos0, (B, 1)))
+            ctx = dataclasses.replace(
+                ctx, positions=jnp.reshape(pos0, (B, 1)) + jnp.arange(S)[None])
         else:
             ctx = dataclasses.replace(
                 ctx, positions=jnp.broadcast_to(jnp.arange(S)[None], (B, S)))
@@ -406,14 +427,13 @@ def apply_model(
         return logits, new_cache, aux
 
     emb0 = x if cfg.family == "hybrid" else None
-    shared_points = set(cfg.shared_attn_points())
+    calls = {layer: k for k, layer in enumerate(cfg.hybrid_layer_ids)}
     caches = cache["layers"] if cache is not None else None
     shared_caches = list(cache.get("shared", ())) if cache is not None else []
     new_layer_caches: List[Any] = []
     new_shared_caches: List[Any] = []
 
     li = 0          # global layer index (cache slot)
-    si = 0          # shared-attn invocation index
     for gi, (kind, count) in enumerate(group_structure(cfg)):
         if kind == "enc":
             li += count
@@ -432,9 +452,17 @@ def apply_model(
                     lambda p_, x_, c_: _apply_moe_block(p_, x_, ctx, c_), ctx)
                 x, c_new, a_ = fn(pl, x, c_in)
             elif kind == "mamba2":
+                mix = None
+                if li in calls:
+                    k = calls[li]
+                    mix, sc_new = _apply_shared_block(
+                        params["shared"], k, x, emb0, ctx,
+                        shared_caches[k] if shared_caches else None)
+                    new_shared_caches.append(sc_new)
                 fn = _maybe_ckpt(
-                    lambda p_, x_, c_: _apply_mamba_block(p_, x_, ctx, c_), ctx)
-                x, c_new, a_ = fn(pl, x, c_in)
+                    lambda p_, x_, c_, m_: _apply_mamba_block(p_, x_, ctx, c_,
+                                                              m_), ctx)
+                x, c_new, a_ = fn(pl, x, c_in, mix)
             elif kind == "rwkv6":
                 fn = _maybe_ckpt(
                     lambda p_, x_, c_: _apply_rwkv_block(p_, x_, ctx, c_), ctx)
@@ -462,12 +490,6 @@ def apply_model(
             aux = aux + a_
             new_layer_caches.append(c_new)
             li += 1
-            if cfg.family == "hybrid" and (li - 1) in shared_points:
-                sc_in = shared_caches[si] if shared_caches else None
-                x, sc_new = _apply_shared_block(params["shared"], x, emb0, ctx,
-                                                sc_in)
-                new_shared_caches.append(sc_new)
-                si += 1
 
     x = apply_norm(params["final_norm"], x, cfg)
     if return_hidden:
@@ -555,77 +577,62 @@ def _apply_groups_scanned(params, x, ctx: Ctx, cache, enc_out):
     return x, (new_cache if serving else None), aux_total
 
 
+def _index(tree, i):
+    """``tree`` with every leaf indexed by ``i`` on its leading axis."""
+    return jax.tree.map(lambda a: a[i], tree)
+
+
 def _scan_hybrid(params, pstack, x, ctx: Ctx, cache):
-    """zamba2: scan over [shared_attn_every mamba layers + shared block]
-    units, remainder layers unrolled."""
+    """zamba2 under scan-over-layers: the plain Mamba layers between two
+    hybrid calls are one ``lax.scan`` each; each hybrid layer (its shared
+    block call and its Mamba layer) is unrolled, so calls at irregular
+    positions and alternating blocks need no dynamic indexing."""
     cfg = ctx.cfg
-    unit = cfg.shared_attn_every
-    n_units = len(cfg.shared_attn_points())
-    n_scan = n_units * unit
-    rem = cfg.n_layers - n_scan
     emb0 = x
     serving = ctx.mode in ("prefill", "decode")
-
-    p_scan = jax.tree.map(
-        lambda a: a[:n_scan].reshape(n_units, unit, *a.shape[1:]), pstack)
-    p_rem = jax.tree.map(lambda a: a[n_scan:], pstack)
     c_g = cache.get("g0") if cache is not None else None
     c_sh = cache.get("shared") if cache is not None else None
-    c_scan = (jax.tree.map(
-        lambda a: a[:n_scan].reshape(n_units, unit, *a.shape[1:]), c_g)
-        if c_g is not None else None)
-    c_rem = (jax.tree.map(lambda a: a[n_scan:], c_g)
-             if c_g is not None else None)
+    states, shared_states = [], []
 
-    def unit_body(x_c, xs):
-        if c_scan is not None:
-            p_u, c_u, sc = xs
-        else:
-            p_u, c_u, sc = xs, None, None
-        new_states = []
-        a_tot = jnp.float32(0.0)
-        for j in range(unit):
-            p_l = jax.tree.map(lambda a: a[j], p_u)
-            c_l = jax.tree.map(lambda a: a[j], c_u) if c_u is not None else None
-            x_c, c_new, a_ = _apply_mamba_block(p_l, x_c, ctx, c_l)
-            new_states.append(c_new)
-            a_tot = a_tot + a_
-        x_c, sc_new = _apply_shared_block(params["shared"], x_c, emb0, ctx, sc)
-        if serving:
-            stacked_states = jax.tree.map(
-                lambda *ls: jnp.stack(ls), *new_states)
-        else:
-            stacked_states, sc_new = None, None
-        return x_c, (stacked_states, sc_new, a_tot)
+    def body(x_c, xs):
+        p_l, c_l = xs if c_g is not None else (xs, None)
+        y, c_new, _ = _apply_mamba_block(p_l, x_c, ctx, c_l)
+        return y, (c_new if serving else None)
 
     if ctx.mode == "train" and cfg.remat != "none":
-        unit_body = jax.checkpoint(unit_body)
-    xs = (p_scan, c_scan, c_sh) if c_scan is not None else p_scan
-    x, (states_s, sh_s, auxs) = jax.lax.scan(unit_body, x, xs)
+        body = jax.checkpoint(body)
 
-    rem_states = []
-    aux_rem = jnp.float32(0.0)
-    for j in range(rem):
-        p_l = jax.tree.map(lambda a: a[j], p_rem)
-        c_l = jax.tree.map(lambda a: a[j], c_rem) if c_rem is not None else None
-        fn = _maybe_ckpt(lambda p_, x_, c_: _apply_mamba_block(p_, x_, ctx, c_),
-                         ctx)
-        x, c_new, a_ = fn(p_l, x, c_l)
-        rem_states.append(c_new)
-        aux_rem = aux_rem + a_
+    def segment(lo, hi, x_c):
+        """Plain Mamba layers [lo, hi) as one scan."""
+        if hi <= lo:
+            return x_c
+        xs = _index(pstack, slice(lo, hi))
+        if c_g is not None:
+            xs = (xs, _index(c_g, slice(lo, hi)))
+        x_c, st = jax.lax.scan(body, x_c, xs)
+        states.append(st)
+        return x_c
 
-    nc_g = None
-    if serving:
-        flat = jax.tree.map(
-            lambda a: a.reshape(n_scan, *a.shape[2:]), states_s)
-        if rem_states:
-            rem_stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *rem_states)
-            nc_g = jax.tree.map(
-                lambda a, b: jnp.concatenate([a, b], axis=0), flat,
-                rem_stacked)
-        else:
-            nc_g = flat
-    return x, nc_g, (sh_s if serving else None), jnp.sum(auxs) + aux_rem
+    hybrid = _maybe_ckpt(
+        lambda p_, x_, c_, m_: _apply_mamba_block(p_, x_, ctx, c_, m_), ctx)
+    lo = 0
+    for k, layer in enumerate(cfg.hybrid_layer_ids):
+        x = segment(lo, layer, x)
+        c_l = _index(c_g, layer) if c_g is not None else None
+        sc = _index(c_sh, k) if c_sh is not None else None
+        mix, sc_new = _apply_shared_block(params["shared"], k, x, emb0, ctx, sc)
+        x, c_new, _ = hybrid(_index(pstack, layer), x, c_l, mix)
+        states.append(jax.tree.map(lambda a: a[None], c_new))
+        shared_states.append(sc_new)
+        lo = layer + 1
+    x = segment(lo, cfg.n_layers, x)
+
+    if not serving:
+        return x, None, None, jnp.float32(0.0)
+    nc_g = jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *states)
+    nc_sh = (jax.tree.map(lambda *a: jnp.stack(a), *shared_states)
+             if shared_states else None)
+    return x, nc_g, nc_sh, jnp.float32(0.0)
 
 
 def head_logits(params, x: jax.Array, ctx: Ctx) -> jax.Array:
