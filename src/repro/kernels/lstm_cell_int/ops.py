@@ -19,9 +19,18 @@ def lstm_window_int(x: jax.Array, w: jax.Array, b: jax.Array,
 
     One template dispatch per window: pads the batch to the block size, runs
     the fused kernel (weights + biases VMEM-resident, both ROMs in SMEM),
-    slices the padding back off. Padded rows compute on zero inputs and are
-    discarded — rows are independent, so real rows are bit-identical to the
-    unpadded run. ``interpret`` is the caller's
+    slices the padding back off. Padded windows compute on zero inputs and
+    are discarded — windows are independent, so real ones are bit-identical
+    to the unpadded run.
+
+    The kernel's layout follows the static batch size
+    (:func:`~repro.kernels.lstm_cell_int.kernel.pick_schedule`): up to about
+    40 windows at hidden 20 the batch sits on sublanes and the gates on
+    lanes (``rows``); above that the batch sits on lanes and the gates on
+    sublanes (``lanes``), so each compare-select ROM sweep — the kernel's
+    dominant cost — covers only the gate rows that read that ROM. Either
+    way the arguments and the result keep the layouts above; the re-lay
+    happens inside this jitted call. ``interpret`` is the caller's
     :func:`repro.kernels.use_interpret` — a static argument, so a program
     traced for the chip is never replayed in interpret mode or vice versa.
     """

@@ -10,6 +10,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.lstm_cell.ops import lstm_window
 from repro.kernels.lstm_cell.ref import lstm_window_ref
 from repro.kernels.lstm_cell_int import (CellSpec, lstm_window_int,
+                                         lstm_window_int_pallas,
                                          lstm_window_int_ref)
 from repro.kernels.mamba2.ops import ssd
 from repro.kernels.quant_matmul.ops import quant_matmul
@@ -17,6 +18,7 @@ from repro.kernels.quant_matmul.ref import quant_matmul_ref, quantize_act
 from repro.kernels.rwkv6.ops import wkv6
 from repro.model.rwkv import wkv6_reference
 from repro.model.ssm import ssd_reference
+from repro.obs import capture
 from repro.quant.fixedpoint import FxpFormat
 from repro.quant.ptq import quantize_params_int8
 
@@ -112,9 +114,15 @@ def test_template_registry_matches_packages():
     (FxpFormat(9, 4), FxpFormat(12, 9), FxpFormat(16, 8)),  # §4 envelope edge
 ], ids=["act8-w8", "act9-w12"])
 @pytest.mark.parametrize("shape", [(1, 6, 1, 20), (7, 6, 3, 16),
-                                   (64, 4, 2, 8), (200, 6, 1, 20)])
+                                   (64, 4, 2, 8), (200, 6, 1, 20),
+                                   # either side of the rows/lanes threshold
+                                   (40, 6, 1, 20), (64, 6, 1, 20),
+                                   # lanes across a padded 128-lane tile,
+                                   # and a stacked cell (d_in = hidden)
+                                   (300, 6, 1, 20), (130, 6, 20, 20)])
 def test_lstm_window_int(shape, fmts):
-    """Fused integer window vs the per-step oracle: EXACT int equality."""
+    """Fused integer window vs the per-step oracle: EXACT int equality,
+    in both layouts (``pick_schedule``)."""
     import numpy as np
 
     B, S, din, hid = shape
@@ -135,6 +143,30 @@ def test_lstm_window_int(shape, fmts):
     y_r = lstm_window_int_ref(x, w, b, sig, tanh, spec=spec)
     assert y_k.dtype == jnp.int32 and y_k.shape == (B, S, hid)
     assert np.array_equal(np.asarray(y_k), np.asarray(y_r))
+
+
+@pytest.mark.parametrize("batch,schedule", [
+    (1, "rows"), (40, "rows"), (41, "lanes"), (256, "lanes")])
+def test_lstm_window_int_schedule_counter(batch, schedule):
+    """Each trace of the fused kernel counts the layout it picked from the
+    static batch size: rows up to 40 windows at hidden 20, lanes above."""
+    A = FxpFormat(8, 4)
+    spec = CellSpec(seq_len=6, d_in=1, hidden=20, act_fmt=A,
+                    state_fmt=FxpFormat(16, 8), w_fmt=FxpFormat(8, 6),
+                    sig_lo=A.lo, tanh_lo=A.lo)
+    depth = 2 ** A.total_bits
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    with capture() as cap:
+        jax.eval_shape(
+            lambda *a: lstm_window_int_pallas(*a, spec=spec, block_b=128,
+                                              interpret=True),
+            i32(batch, 6, 1), i32(21, 80), i32(80), i32(depth), i32(depth))
+    counts = {k: c.value for k, c in cap.metrics.counters.items()
+              if k.startswith("rtl.kernels.lstm_window_int.")}
+    assert counts == {f"rtl.kernels.lstm_window_int.schedule.{schedule}": 1}
 
 
 # --------------------------------------------------------------------------

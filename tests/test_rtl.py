@@ -224,8 +224,9 @@ EDGE_FMTS = {
 
 
 @pytest.mark.parametrize("fmts", sorted(EDGE_FMTS))
-@pytest.mark.parametrize("mode", ["fused", "pallas", "jnp"])
-@pytest.mark.parametrize("batch", [1, 7, 64])
+@pytest.mark.parametrize("batch,mode", [
+    (batch, mode) for mode in ("fused", "pallas", "jnp") for batch in (1, 7, 64)
+] + [(130, "fused")])       # the fused kernel's lanes layout, padded tile
 @pytest.mark.parametrize("n_layers", [1, 2])
 def test_emulator_bit_exact_all_paths(mode, batch, n_layers, fmts):
     """Every execution path × batch size × stacked depth × envelope-edge

@@ -63,8 +63,9 @@ def _compiled_text(fn, *args) -> str:
 
 @pytest.mark.parametrize("fmts", [(A8, W8), (A9, W12)],
                          ids=["act8-w8", "act9-w12"])
-@pytest.mark.parametrize("batch", [1, 256, 4096])
+@pytest.mark.parametrize("batch", [1, 64, 256, 4096])
 def test_fused_lstm_kernel_compiles(one_chip, batch, fmts):
+    """Both layouts of the fused kernel: rows at batch 1, lanes above."""
     act, w_fmt = fmts
     spec = CellSpec(seq_len=6, d_in=1, hidden=20, act_fmt=act,
                     state_fmt=C16, w_fmt=w_fmt, sig_lo=act.lo,
